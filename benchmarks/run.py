@@ -63,6 +63,7 @@ from repro.configs.costmodel import CostModelConfig
 from repro.core import models as CM
 from repro.core import trainer as TR
 from repro.ir import dataset as DS
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def _row(name, us, derived):
@@ -164,11 +165,8 @@ def _ragged_ids(rng, B, S, vocab):
 
 def _cost_bytes_flops(fn, *args):
     """(bytes accessed, flops) of ``fn`` from the compiled module's
-    cost_analysis (list-shaped on some backends)."""
-    compiled = jax.jit(fn).lower(*args).compile()
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
+    cost_analysis."""
+    ca = jax.jit(fn).lower(*args).compile().cost_analysis()
     return float(ca.get("bytes accessed", 0.0)), float(ca.get("flops", 0.0))
 
 
@@ -178,9 +176,11 @@ def kernel_bench(full: bool = False, seed: int = 0):
 
     Per shape: f32 parity (max abs err vs conv_apply/lstm_apply), wall
     time, and modeled HBM traffic — unfused bytes from the compiled
-    reference's ``cost_analysis()``, fused bytes from the kernel's
-    ids+params+out analytic model — both fed through
-    ``launch/roofline.py`` for roofline fractions. bf16 parity pools
+    reference's ``cost_analysis()``, fused bytes from
+    ``fused_forward_bytes`` (ids, the XLA gather's embedding rows and
+    ``(B, S, E)`` activations written and read back, the other params,
+    out) — both fed through ``launch/roofline.py`` for roofline
+    fractions. bf16 parity pools
     predictions across every shape and reports per-head Spearman vs the
     f32 reference. ``gate.py::gate_kernel_bench`` enforces f32 parity,
     bf16 Spearman >= 0.99, and an aggregate >= 3x traffic reduction
@@ -247,8 +247,10 @@ def kernel_bench(full: bool = False, seed: int = 0):
                        "fused_us": us_fused}
                 if kind == "conv1d":
                     # unfused traffic: what XLA's compiled module says it
-                    # moves; fused traffic: one read of ids+params, one
-                    # write of the predictions (the kernel's contract)
+                    # moves; fused traffic: ids, the XLA gather (the
+                    # embedding rows read, (B, S, E) activations written
+                    # and read back by the kernel), one read of the
+                    # other params, one write of the predictions
                     ub, fl = _cost_bytes_flops(apply_fn, p32, ids)
                     fb = float(KOPS.fused_forward_bytes(p32, B, S))
                     unfused_bytes += ub
@@ -1779,6 +1781,7 @@ def main() -> None:
                          "trajectory file after the run")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     summaries = {}
     for name, fn in BENCHES.items():

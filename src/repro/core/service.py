@@ -108,13 +108,14 @@ class CostModelService:
     fast_encode: bool = True
     ids_cache_size: int = 8192
     # Serve through the fused Pallas forward (kernels/ops.forward_apply;
-    # interpret mode on CPU) instead of the plain-jnp apply. conv1d runs
-    # the full ids-in/predictions-out kernel (gather + tower + FC +
-    # heads in one pallas_call); lstm runs the VMEM-carry recurrence
-    # kernel. Composes with dtype="bf16": kernels read bf16 params but
-    # accumulate f32 in-kernel (drift vs f32 is Spearman-gated in tests
-    # and kernel_bench). The kernels' accumulation order differs from
-    # XLA's, so f32 parity is "allclose", not bit-identical.
+    # interpret mode on the CPU backend, compiled everywhere else, with
+    # no fallback) instead of the plain-jnp apply. conv1d gathers in XLA
+    # and runs tower + FC + heads in one pallas_call; lstm runs the
+    # VMEM-carry recurrence kernel. Composes with dtype="bf16": kernels
+    # read bf16 params but accumulate f32 in-kernel (drift vs f32 is
+    # Spearman-gated in tests and kernel_bench). The kernels'
+    # accumulation order differs from XLA's, so f32 parity is
+    # "allclose", not bit-identical.
     use_kernel: bool = False
     buckets: Optional[Tuple[int, ...]] = None   # None -> power-of-two ladder
     # batch sizes forward passes are padded up to (None -> power-of-two
@@ -170,10 +171,13 @@ class CostModelService:
             params = jax.tree.map(_cast, params)
         n_bytes = sum(np.asarray(x).nbytes for x in jax.tree.leaves(params))
         if n_bytes <= 16 * 2**20:
-            self._apply = jax.jit(lambda ids: apply_fn(params, ids))
+            self._jit = jax.jit(lambda ids: apply_fn(params, ids))
+            self._jit_args = ()
+            self._apply = self._jit
         else:
             dev_params = jax.device_put(params)
-            jitted = jax.jit(apply_fn)
+            jitted = self._jit = jax.jit(apply_fn)
+            self._jit_args = (dev_params,)
             self._apply = lambda ids: jitted(dev_params, ids)
         self.heads = CM.model_heads(self.params) or (
             self.target or "prediction",)
@@ -713,6 +717,13 @@ class CostModelService:
                     self._apply(np.zeros((b, s), np.int32)))
                 n += 1
         return n
+
+    def compiled_forward(self, batch: int, bucket: int):
+        """The compiled forward program for one (batch, bucket) shape, for
+        inspection: e.g. ``"tpu_custom_call" in .as_text()`` shows that
+        a ``use_kernel`` service runs the compiled Pallas kernel."""
+        ids = jax.ShapeDtypeStruct((batch, bucket), np.int32)
+        return self._jit.lower(*self._jit_args, ids).compile()
 
     def predict_all(self, graphs: Sequence[Graph]) -> Dict[str, np.ndarray]:
         """All targets for every graph from one cached, batched, bucketed

@@ -38,6 +38,7 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core import models as CM
 from repro.data import pipeline as PIPE
@@ -72,23 +73,25 @@ def make_loss_fn(apply_fn, heads: Optional[Tuple[str, ...]] = None):
     return loss_fn
 
 
-def make_sgd_step(apply_fn, opt_cfg, grad_transform=None,
-                  heads: Optional[Tuple[str, ...]] = None):
-    """Single-step builder for custom/external loops (notebooks, tests).
-
-    The TrainEngine composes the same pieces itself because its step also
-    threads the compression error state; this stays the minimal public
-    building block."""
+def make_train_step(apply_fn, opt_cfg,
+                    heads: Optional[Tuple[str, ...]] = None):
+    """The engine's jitted step: ``(state, ids, y) -> (state, loss)``
+    with ``state = (params, opt_state, err)``. ``err`` is the int8
+    error-feedback state of gradient compression, or None when
+    compression is off. Public so a compile test can build the exact
+    program the engine runs."""
     loss_fn = make_loss_fn(apply_fn, heads)
 
-    def step(params, opt_state, ids, y):
-        loss, grads = jax.value_and_grad(loss_fn)(params, ids, y)
-        if grad_transform is not None:
-            grads = grad_transform(grads)
-        params, opt_state, _ = adamw.apply_updates(params, grads, opt_state,
-                                                   opt_cfg)
-        return params, opt_state, loss
-    return step
+    @jax.jit
+    def train_step(state, ids, yy):
+        params, opt_state, err = state
+        loss, grads = jax.value_and_grad(loss_fn)(params, ids, yy)
+        if err is not None:
+            grads, err = compress.compress_grads(grads, err)
+        params, opt_state, _ = adamw.apply_updates(
+            params, grads, opt_state, opt_cfg)
+        return (params, opt_state, err), loss
+    return train_step
 
 
 @dataclass(frozen=True)
@@ -140,6 +143,7 @@ class TrainEngine:
         self.ecfg = dataclasses.replace(engine or EngineConfig(),
                                         **overrides)
         self.init_fn, self.apply_fn, self.axes_fn = CM.get_model(kind)
+        self.mesh = None                  # the ("data", "model") mesh of fit
 
     # ------------------------------------------------------------- pipeline
     def bucket_assignments(self, train: DS.CostDataset
@@ -175,6 +179,18 @@ class TrainEngine:
                            bucket_by=self.bucket_assignments(train),
                            bucket_mode=e.bucket_mode)
 
+    def place_batch(self, batch: Dict[str, np.ndarray]
+                    ) -> Dict[str, jax.Array]:
+        """Host batch -> device arrays. On a multi-device mesh (set by
+        :meth:`fit`) every array is split along the mesh's ``data`` axis
+        on its leading (batch) dim; on one device it goes to the default
+        device."""
+        mesh = self.mesh
+        if mesh is None or mesh.devices.size == 1:
+            return {k: jnp.asarray(v) for k, v in batch.items()}
+        sharding = NamedSharding(mesh, P("data"))
+        return {k: jax.device_put(v, sharding) for k, v in batch.items()}
+
     # ------------------------------------------------------------------ fit
     def fit(self, train: DS.CostDataset, *,
             on_step: Optional[Callable] = None) -> TrainResult:
@@ -190,7 +206,13 @@ class TrainEngine:
             y = y.astype(np.float32)
         loader = self.make_loader(train, y)
 
-        mesh = jax.make_mesh((e.mesh_data, e.mesh_model), ("data", "model"))
+        # Auto axes: params and batch carry explicit NamedShardings and
+        # GSPMD propagates the rest (JAX's default Explicit axes would
+        # reject e.g. the emb gather, whose table dim and batch dim both
+        # claim "data")
+        mesh = self.mesh = jax.make_mesh(
+            (e.mesh_data, e.mesh_model), ("data", "model"),
+            axis_types=(AxisType.Auto, AxisType.Auto))
         if mesh.devices.size > 1:
             rules = ShardingRules(mesh)
             axes = self.axes_fn(self.cfg, heads=self.heads) if self.heads \
@@ -204,17 +226,7 @@ class TrainEngine:
                                     weight_decay=e.weight_decay)
         err0 = compress.init_error_state(params) if e.compress_grads \
             else None
-        loss_fn = make_loss_fn(self.apply_fn, self.heads)
-
-        @jax.jit
-        def train_step(state, ids, yy):
-            params, opt_state, err = state
-            loss, grads = jax.value_and_grad(loss_fn)(params, ids, yy)
-            if err is not None:
-                grads, err = compress.compress_grads(grads, err)
-            params, opt_state, _ = adamw.apply_updates(
-                params, grads, opt_state, opt_cfg)
-            return (params, opt_state, err), loss
+        train_step = make_train_step(self.apply_fn, opt_cfg, self.heads)
 
         sup = fault.TrainSupervisor(e.ckpt_dir, save_every=e.save_every,
                                     keep=e.keep)
@@ -232,8 +244,8 @@ class TrainEngine:
 
         def step_fn(state, step):
             batch = next(it)
-            state, loss = train_step(state, jnp.asarray(batch["ids"]),
-                                     jnp.asarray(batch["y"]))
+            batch = self.place_batch(batch)
+            state, loss = train_step(state, batch["ids"], batch["y"])
             last[0] = loss     # device value; sync only at log points
             return state
 
@@ -261,6 +273,7 @@ class TrainEngine:
         # final_loss is then NaN (nothing ran) and steps_per_s 0 by design
         stats = {"final_loss": float(last[0]),
                  "steps": float(steps_run),
+                 "start_step": float(start),
                  "wall_time_s": wall,
                  "steps_per_s": steps_run / max(wall, 1e-9)}
         return TrainResult(params=state[0], stats=stats, history=history,

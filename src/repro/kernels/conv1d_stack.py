@@ -4,37 +4,38 @@ The paper's deployed model runs thousands of inferences per compilation
 session, so this is the perf-critical hot spot. A naive XLA lowering runs
 each Conv1D as a separate HBM round-trip (6 layers x (B,S,C) activations);
 at C=64 the tower is heavily memory-bound (arithmetic intensity ~= fs*C/6
-FLOPs/byte). Two fusion levels live here:
+FLOPs/byte). Two fusion levels live here, both taking the same
+``(B, S, E)`` embedded activations:
 
 * :func:`conv1d_stack_fused` — the tower only: embedded activations in,
-  pooled features out, whole tower held in VMEM (the PR-6 kernel, kept
-  as the composable building block).
+  masked-pooled features out.
 * :func:`conv_forward_fused` — the full serving forward: **token ids
-  in, per-target predictions out**. The embedding gather + pad mask run
-  inside the grid step (the ``(B,S,E)`` embedded activations are never
-  materialized in HBM — previously the single largest remaining HBM
-  round trip), and the FC stack + stacked per-target linear heads fold
-  into the same call. One HBM read of ids and params yields the
-  ``(B, n_heads)`` normalized predictions.
+  in, per-target predictions out**. The embedding gather and pad mask
+  run in XLA in the wrapper (one HBM write + read of the ``(B, S, E)``
+  activations); the conv tower, max-pool, FC stack and stacked
+  per-target linear heads run in one ``pallas_call``, so no conv layer
+  or pooled/FC feature ever leaves VMEM. Keeping the gather out of the
+  kernel keeps the embedding table out of VMEM: Mosaic cannot lower an
+  in-kernel ``jnp.take`` gather, and a pinned table would not fit VMEM
+  at ``COSTMODEL_100M`` widths (64 MiB in f32).
 
 TPU mapping:
-* channels sit on the 128-wide lane dimension (C padded to 128);
-* the embedding table is pinned whole in VMEM (index_map block 0), so
-  the gather is a VMEM-local dynamic lookup, not an HBM gather;
+* channels sit on the lane dimension;
 * sequence sits on sublanes; each conv tap is a (S, Cin) @ (Cin, Cout)
   MXU matmul — the fs-tap conv = fs shifted matmuls accumulated in fp32;
 * grid over batch tiles; weights are broadcast to every grid step
-  (index_map pins them to block 0).
+  (index_map pins them to block 0), so they are held whole in VMEM.
 
 Accumulation is float32 regardless of the parameter dtype, so bf16-cast
 params (quantized serving) run bf16 HBM reads with f32 in-kernel math;
 predictions always come out float32.
 
-VMEM budget per grid step (defaults: bblk=8, S<=1024, C<=128, V<=8192):
-    emb table 8192*128*4 = 4 MiB, x tile 8*1024*128*4 = 4 MiB, two
-    ping-pong layer buffers 8 MiB, weights sum(fs*C*C)*4 + FC/head
-    stacks << 1 MiB -> fits the ~16 MiB VMEM of v5e (bf16 params halve
-    the table and weight terms).
+VMEM per grid step (bblk=8): the x tile bblk*S*E*4 B (0.5 MiB at
+``COSTMODEL_BASE``: S=256, E=64), two f32 layer buffers of bblk*S*C*4 B
+each, and every pinned weight. At ``COSTMODEL_BASE`` the weights are
+well under 1 MiB; at ``COSTMODEL_100M`` the pinned conv + FC weights
+alone are 184 MiB in f32 and do not fit, so that width needs a non-pinned
+(streamed) weight layout before it can serve through this kernel.
 """
 from __future__ import annotations
 
@@ -64,7 +65,7 @@ def _tower(h, mask, refs, n_layers, filter_sizes, *, masked_pool):
     positions from the max (the tower-only kernel's contract, matching
     conv1d_stack_ref(mask); all-pad rows pool to the ReLU floor of 0);
     the full-forward kernel pools every position, exactly matching
-    core/models.py::conv_apply."""
+    core/models.py::conv_apply, and passes ``mask=None``."""
     S = h.shape[1]
     for i in range(n_layers):
         w = refs[2 * i][...].astype(jnp.float32)      # (fs, Cin, Cout)
@@ -134,27 +135,18 @@ def conv1d_stack_fused(x: jax.Array, weights: Sequence[jax.Array],
     return out[:B]
 
 
-def _forward_kernel(ids_ref, emb_ref, *refs, n_layers: int, filter_sizes,
-                    n_fc: int):
-    """Ids-in / predictions-out kernel.
+def _forward_kernel(x_ref, *refs, n_layers: int, filter_sizes, n_fc: int):
+    """Embedded-activations-in / predictions-out kernel.
 
     refs = (w0, b0, ..., w{L-1}, b{L-1},          conv tower
             fw0, fb0, ..., fw{n_fc-1}, fb{n_fc-1}, hidden FC stack
             head_w, head_b, out_ref)              stacked linear heads
     """
     out_ref = refs[-1]
-    ids = ids_ref[...]                            # (bblk, S) int32
-    emb = emb_ref[...].astype(jnp.float32)        # (V, E), VMEM-resident
-    # embedding gather + pad mask, entirely on-chip: the (bblk, S, E)
-    # activations live only in VMEM
-    x = jnp.take(emb, ids.reshape(-1), axis=0).reshape(
-        ids.shape + (emb.shape[1],))
-    mask = (ids != 0).astype(jnp.float32)         # PAD id is 0
-    x = x * mask[..., None]
-    conv_refs = refs[:2 * n_layers]
+    x = x_ref[...].astype(jnp.float32)            # (bblk, S, E), pads = 0
     # pool over every position (pads included), exactly like conv_apply:
     # the serving tier's bucket pad_slack relies on those semantics
-    pooled = _tower(x, mask, conv_refs, n_layers, filter_sizes,
+    pooled = _tower(x, None, refs[:2 * n_layers], n_layers, filter_sizes,
                     masked_pool=False)
     # hidden FC stack (ReLU), then all heads as ONE (F, n_heads) matmul
     off = 2 * n_layers
@@ -180,10 +172,9 @@ def conv_forward_fused(ids: jax.Array, emb: jax.Array,
 
     ids: (B, S) int32 (PAD id 0); emb: (V, E); head_w: (F, n_heads)
     with the per-target head columns stacked. Params may be f32 or bf16
-    — accumulation is f32 in-kernel either way. One HBM read of ids and
-    params, one HBM write of the predictions; no intermediate tensor
-    (embedded activations, conv layers, pooled/FC features) ever leaves
-    VMEM."""
+    — accumulation is f32 in-kernel either way. The embedding gather and
+    pad mask run in XLA; everything after them is one ``pallas_call``
+    whose only HBM write is the predictions."""
     B, S = ids.shape
     n_layers = len(conv_weights)
     filter_sizes = tuple(int(w.shape[0]) for w in conv_weights)
@@ -192,11 +183,12 @@ def conv_forward_fused(ids: jax.Array, emb: jax.Array,
     Bp = ((B + bblk - 1) // bblk) * bblk
     if Bp != B:
         ids = jnp.pad(ids, ((0, Bp - B), (0, 0)))   # pad rows are all-PAD
+    mask = (ids != 0).astype(emb.dtype)             # PAD id is 0
+    x = jnp.take(emb, ids, axis=0) * mask[..., None]
     grid = (Bp // bblk,)
 
-    in_specs = [pl.BlockSpec((bblk, S), lambda i: (i, 0)),
-                _pinned_spec(emb.shape)]
-    operands = [ids, emb]
+    in_specs = [pl.BlockSpec((bblk, S, emb.shape[1]), lambda i: (i, 0, 0))]
+    operands = [x]
     for w, b in zip(conv_weights, conv_biases):
         in_specs += [_pinned_spec(w.shape), _pinned_spec(b.shape)]
         operands += [w, b]

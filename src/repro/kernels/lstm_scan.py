@@ -9,6 +9,10 @@ matmul + elementwise chain per step, spilling the ``(B, H)`` carry to
 HBM between steps. This kernel runs the whole sequence loop inside one
 grid step:
 
+* the wrapper makes the inputs time-major (``(S, B, 4H)`` gates and an
+  ``(S, B, 1)`` mask), so step ``t`` reads ``xw_ref[t]`` and
+  ``mask_ref[t]`` on the ref's leading dim — the only dynamic index
+  Mosaic lowers inside a ``fori_loop``;
 * gates ``h @ wh`` as an MXU matmul per step (``wh`` pinned in VMEM);
 * the ``(h, c)`` carry lives in VMEM registers across the
   ``fori_loop`` — zero HBM traffic between timesteps;
@@ -20,10 +24,10 @@ Params/activations may be f32 or bf16; the carry and all gate math are
 float32 in-kernel either way (bf16 HBM reads, f32 accumulation), and
 the final hidden state comes out float32.
 
-VMEM per grid step (bblk=8, S<=1024, H<=128): xw tile
-8*1024*512*4 = 16 MiB at H=128 f32 — tight, so serving configs with
-long buckets should pass bf16 ``xw`` (halves it) or drop ``bblk``.
-At the repo's default H<=64 the tile is <=8 MiB and f32 fits easily.
+VMEM per grid step: the xw tile S*bblk*4H*itemsize, double-buffered.
+At ``COSTMODEL_BASE`` (S<=256, bblk=8, H=128) that is 4 MiB per buffer
+in f32, inside v5e's default 16 MiB scoped VMEM; S=1024 at H=128 f32
+would need 32 MiB and must pass bf16 ``xw`` or a smaller ``bblk``.
 """
 from __future__ import annotations
 
@@ -35,14 +39,12 @@ from jax.experimental import pallas as pl
 
 
 def _lstm_kernel(xw_ref, mask_ref, wh_ref, out_ref, *, hidden: int):
-    xw = xw_ref[...].astype(jnp.float32)      # (bblk, S, 4H)
-    mask = mask_ref[...]                      # (bblk, S) f32
     wh = wh_ref[...].astype(jnp.float32)      # (H, 4H)
-    bblk, S, _ = xw.shape
+    S, bblk, _ = xw_ref.shape
 
     def step(t, carry):
         h, c = carry
-        gates = xw[:, t, :] + h @ wh
+        gates = xw_ref[t].astype(jnp.float32) + h @ wh    # (bblk, 4H)
         i, f, g, o = jnp.split(gates, 4, axis=-1)
         i = jax.nn.sigmoid(i)
         f = jax.nn.sigmoid(f + 1.0)           # paper's forget-gate bias
@@ -50,7 +52,7 @@ def _lstm_kernel(xw_ref, mask_ref, wh_ref, out_ref, *, hidden: int):
         g = jnp.tanh(g)
         c_new = f * c + i * g
         h_new = o * jnp.tanh(c_new)
-        keep = mask[:, t][:, None]
+        keep = mask_ref[t]                    # (bblk, 1) f32
         return (h_new * keep + h * (1 - keep),
                 c_new * keep + c * (1 - keep))
 
@@ -74,17 +76,19 @@ def lstm_scan_fused(xw: jax.Array, mask: jax.Array, wh: jax.Array, *,
     if Bp != B:
         xw = jnp.pad(xw, ((0, Bp - B), (0, 0), (0, 0)))
         mask = jnp.pad(mask, ((0, Bp - B), (0, 0)))
+    xw_t = xw.transpose(1, 0, 2)              # (S, Bp, 4H)
+    mask_t = mask.T[..., None]                # (S, Bp, 1)
 
     out = pl.pallas_call(
         functools.partial(_lstm_kernel, hidden=hidden),
         grid=(Bp // bblk,),
         in_specs=[
-            pl.BlockSpec((bblk, S, four_h), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bblk, S), lambda i: (i, 0)),
+            pl.BlockSpec((S, bblk, four_h), lambda i: (0, i, 0)),
+            pl.BlockSpec((S, bblk, 1), lambda i: (0, i, 0)),
             pl.BlockSpec(wh.shape, lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bblk, hidden), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, hidden), jnp.float32),
         interpret=interpret,
-    )(xw, mask, wh)
+    )(xw_t, mask_t, wh)
     return out[:B]

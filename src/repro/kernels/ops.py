@@ -3,19 +3,22 @@
 Three serving entry points, all drop-ins for ``core/models.py`` applies:
 
 * :func:`conv_tower_apply` — mirrors ``conv_apply`` but runs the fused
-  Conv1D+ReLU+MaxPool tower kernel (embedding gather still in plain
-  jnp; kept for composability and as the bench's half-fused rung).
+  Conv1D+ReLU+MaxPool tower kernel (embedding gather in plain jnp;
+  kept for composability and as the bench's half-fused rung).
 * :func:`conv_forward_apply` — the full fusion: token ids in,
-  per-target predictions out, one ``pallas_call`` (embedding gather,
-  pad mask, conv tower, FC stack, and stacked linear heads all inside
-  the grid step — no intermediate HBM traffic).
+  per-target predictions out. The embedding gather and pad mask run in
+  XLA; the conv tower, FC stack and stacked linear heads run in one
+  ``pallas_call`` with no intermediate HBM traffic.
 * :func:`lstm_forward_apply` — mirrors ``lstm_apply``: the input
   projection stays a plain XLA matmul, the recurrence runs in the
   Pallas ``lstm_scan`` kernel with the carry in VMEM.
 
 Params may be f32 or bf16 (accumulation is f32 in-kernel either way).
-On CPU the wrappers transparently use interpret mode; the TPU path
-compiles the same kernels natively.
+Interpret mode is used only when the backend is the CPU (the test
+tier). On any other backend the kernels are compiled, and a kernel the
+compiler refuses raises: there is no fallback to interpret mode or to
+the plain-jnp apply. ``tests/test_tpu_compile.py`` compiles them for a
+described v5e chip.
 """
 from __future__ import annotations
 
@@ -35,6 +38,7 @@ KERNEL_KINDS = ("conv1d", "lstm")
 
 
 def _on_cpu() -> bool:
+    """Interpret mode iff the backend is the CPU (never a fallback)."""
     return jax.default_backend() == "cpu"
 
 
@@ -140,9 +144,14 @@ def forward_apply(kind: str, params, ids, *,
 
 
 def fused_forward_bytes(params, batch: int, seq: int) -> int:
-    """Modeled HBM traffic of one fused conv forward: ids + one read of
-    every param + the predictions. Used by the kernel_bench roofline."""
+    """Modeled HBM traffic of one fused conv forward: ids, the XLA
+    gather (embedding rows read, ``(B, S, E)`` activations written and
+    read back by the kernel), one read of every other param, and the
+    predictions. Used by the kernel_bench roofline."""
     names = model_heads(params)
     n_heads = len(names) if names else 1
+    emb = params["emb"]
+    act = batch * seq * emb.shape[1] * emb.dtype.itemsize
     p_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
-    return batch * seq * 4 + p_bytes + batch * n_heads * 4
+    p_bytes -= emb.size * emb.dtype.itemsize   # only gathered rows are read
+    return batch * seq * 4 + 3 * act + p_bytes + batch * n_heads * 4

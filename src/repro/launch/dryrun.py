@@ -74,13 +74,7 @@ def _mem_summary(compiled) -> Dict[str, float]:
 
 
 def _cost_summary(compiled) -> Dict[str, float]:
-    try:
-        ca = compiled.cost_analysis() or {}
-    except Exception:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return {k: float(v) for k, v in ca.items()
+    return {k: float(v) for k, v in compiled.cost_analysis().items()
             if isinstance(v, (int, float))}
 
 

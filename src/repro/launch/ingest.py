@@ -26,6 +26,7 @@ from repro.core.tokenizer import extend_vocab_oov
 from repro.ir import dataset as DS
 from repro.ir import frontdoor as FD
 from repro.ir import stablehlo as SH
+from repro.runtime.compile_cache import enable_compile_cache
 
 DEFAULT_ARCHS = ("qwen3-0.6b", "xlstm-125m", "whisper-small",
                  "granite-moe-1b-a400m", "starcoder2-3b")
@@ -101,6 +102,7 @@ def main():
                     help="synthetic training-set size")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     svc = build_service(args)
     print(f"service up: heads={list(svc.heads)} "

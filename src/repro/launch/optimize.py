@@ -27,6 +27,7 @@ from repro.ir import dataset as DS
 from repro.ir import samplers
 from repro.opt import evaluate as OE
 from repro.opt import search as OPT
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -84,6 +85,7 @@ def main():
                          "ephemeral)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = CostModelConfig(name="optimize", vocab_size=4096, max_seq=160,
                           embed_dim=64, conv_channels=(64,) * 6,

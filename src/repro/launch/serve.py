@@ -29,6 +29,7 @@ from repro.core.service import (CostModelService, FusionAdvisor,
                                 RecompileAdvisor, UnrollAdvisor)
 from repro.ir import dataset as DS
 from repro.ir import samplers
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def run_clients(server: CostModelServer, graphs, concurrency: int) -> float:
@@ -150,6 +151,7 @@ def main():
                          "endpoint on this port (0 = ephemeral)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = CostModelConfig(name="serve", vocab_size=4096, max_seq=160,
                           embed_dim=64, conv_channels=(64,) * 6,

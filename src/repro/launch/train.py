@@ -10,6 +10,11 @@ loader cursor, preemption handling), and evaluation.
     PYTHONPATH=src python -m repro.launch.train --preset 100m --steps 200 \
         --mesh-data 2   # DP across host devices if available
 
+A checkpoint in ``--ckpt-dir`` is resumed (the driver says so). A
+run that is already complete there trains zero steps, and the driver
+then exits non-zero instead of reporting success; ``--eval-only``
+evaluates such a run.
+
 On the production cluster the same driver runs under the 16x16 mesh with
 ``--mesh-data 16 --mesh-model 16`` (the cost model is small enough that DP
 dominates; model axes shard the embedding + wide FC layers).
@@ -29,6 +34,7 @@ from repro.core import trainer as TR
 from repro.ir import dataset as DS
 from repro.optim import adamw, compress
 from repro.runtime import fault
+from repro.runtime.compile_cache import enable_compile_cache
 
 PRESETS = {"small": COSTMODEL_SMALL, "base": COSTMODEL_BASE,
            "100m": COSTMODEL_100M}
@@ -37,6 +43,7 @@ PRESETS = {"small": COSTMODEL_SMALL, "base": COSTMODEL_BASE,
 def build_or_load_dataset(args, cfg) -> DS.CostDataset:
     path = args.dataset
     if path and os.path.exists(path):
+        print(f"dataset: loading {path} (--seed/--n-graphs not applied)")
         return DS.CostDataset.load(path)
     ds = DS.build_dataset(args.n_graphs, mode=args.mode,
                           max_seq=cfg.max_seq, vocab_size=cfg.vocab_size,
@@ -79,6 +86,7 @@ def main():
                          "drift (e.g. after a JAX upgrade)")
     ap.add_argument("--eval-only", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = PRESETS[args.preset]
     ds = build_or_load_dataset(args, cfg)
@@ -124,12 +132,20 @@ def main():
                                 heads=engine.heads)
     else:
         result = engine.fit(train)
-        if result.stats["steps"]:
-            print(f"trained {result.stats['steps']:.0f} steps in "
-                  f"{result.stats['wall_time_s']:.1f}s "
-                  f"({result.stats['steps_per_s']:.1f} steps/s)")
-        else:
-            print(f"run already complete in {args.ckpt_dir}; evaluating")
+        start = int(result.stats["start_step"])
+        if not result.stats["steps"]:
+            # a finished run found in --ckpt-dir trains nothing: that is
+            # an error, not a success (use --eval-only to evaluate it)
+            raise SystemExit(
+                f"error: the run in {args.ckpt_dir} is already complete "
+                f"at step {start} >= --steps {args.steps}; nothing was "
+                f"trained. Raise --steps to continue it, pass "
+                f"--eval-only to evaluate it, or pick a fresh --ckpt-dir.")
+        if start:
+            print(f"resumed from step {start} in {args.ckpt_dir}")
+        print(f"trained {result.stats['steps']:.0f} steps in "
+              f"{result.stats['wall_time_s']:.1f}s "
+              f"({result.stats['steps_per_s']:.1f} steps/s)")
 
     if engine.heads:
         metrics = TR.evaluate(args.model, cfg, result, test)

@@ -166,7 +166,22 @@ def start_replicas(spec: T.ServiceSpec, n_replicas: int, *,
     client ids are assigned by the caller via
     :meth:`ReplicaTier.client_handle`. ``max_replicas`` pre-allocates
     extra inbox slots so the supervisor can scale the tier up later
-    without re-plumbing existing clients."""
+    without re-plumbing existing clients.
+
+    CPU only: every replica is its own JAX process, and an accelerator
+    (a TPU chip) belongs to one process at a time, so on any other
+    backend this raises before spawning anything."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"the replicated tier spawns one JAX process per replica and "
+            f"runs on the CPU backend only, but this process's backend "
+            f"is {backend!r}: a chip belongs to one process at a time, "
+            f"and this process already holds it. Serve in-process "
+            f"(CostModelServer) instead. Replicas as devices of one "
+            f"process are the ROADMAP design item 'One process per "
+            f"replica on an accelerator'.")
     ctx = mp.get_context("spawn")
     max_replicas = max(n_replicas, max_replicas or n_replicas)
     n_heads = len(spec.norm_stats) if isinstance(spec.norm_stats, dict) \
@@ -205,6 +220,9 @@ def replica_main(replica_id: int, spec: T.ServiceSpec, inbox,
                  server_kw: Dict[str, Any], warmup: bool,
                  ready) -> None:
     """Child entry point (module-level so spawn can import it)."""
+    # the tier is CPU-only (start_replicas refuses other backends), and
+    # N replica processes share one host's cores: single-threaded Eigen
+    # per replica keeps them from oversubscribing each other
     os.environ.setdefault("XLA_FLAGS", "--xla_cpu_multi_thread_eigen=false")
     try:
         from repro.core.server import (CostModelServer,

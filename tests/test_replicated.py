@@ -571,3 +571,18 @@ def test_replica_sigkill_mid_load_recovers(corpus, service, spec):
         if sup is not None:
             sup.stop()
         tier2.stop()
+
+
+def test_start_replicas_refuses_accelerator_backend(monkeypatch):
+    """On a non-CPU backend the tier refuses before spawning anything:
+    a chip belongs to one process, and this process would hold it."""
+    import multiprocessing
+
+    def no_spawn(*a, **k):
+        raise AssertionError("start_replicas reached process spawning")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(multiprocessing, "get_context", no_spawn)
+    with pytest.raises(RuntimeError, match="one process at a time") as e:
+        start_replicas(None, 2)
+    assert "'tpu'" in str(e.value) and "ROADMAP" in str(e.value)

@@ -254,3 +254,34 @@ def test_straggler_detection_and_rebalance():
     mon.beat(3, now=now)
     assert 1 not in mon.stragglers(now=now + 5)
     assert set(mon.stragglers(now=now + 50)) == {0, 1, 2, 3}
+
+
+# ------------------------------------------------------------ compile cache
+def _record_config(monkeypatch):
+    import jax
+    calls = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.__setitem__(k, v))
+    return calls
+
+
+def test_compile_cache_honours_env_dir(monkeypatch, tmp_path):
+    from repro.runtime import compile_cache as CC
+    calls = _record_config(monkeypatch)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert CC.enable_compile_cache() == str(tmp_path)
+    assert "jax_compilation_cache_dir" not in calls   # JAX reads the env
+
+
+def test_compile_cache_default_is_fixed_in_checkout(monkeypatch):
+    from pathlib import Path
+    from repro.runtime import compile_cache as CC
+    calls = _record_config(monkeypatch)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = CC.enable_compile_cache()
+    assert CC.enable_compile_cache() == first
+    assert calls["jax_compilation_cache_dir"] == first
+    root = Path(__file__).resolve().parents[1]
+    assert Path(first) == root / ".jax_cache"
+    ignored = (root / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored

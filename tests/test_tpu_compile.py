@@ -1,0 +1,134 @@
+"""Compile the main path for a described TPU v5e chip (no chip needed).
+
+The TPU compiler is installed with JAX and compiles for a topology that
+is described, not attached, so these tests catch what interpret mode
+cannot: Mosaic refusing a kernel (an unsupported primitive, a block
+shape off the tiling, too much VMEM) and XLA refusing a program. Each
+kernel case asserts ``tpu_custom_call`` in the compiled program, which
+shows the Pallas kernel itself was lowered (not interpret mode, not a
+reference).
+
+This is the only file that describes the chip. The topology is
+described inside a module-scoped fixture, never at import: only one
+process at a time may load the TPU library, and pytest-xdist workers
+all import every test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.costmodel import COSTMODEL_BASE
+from repro.core import models as CM
+from repro.core import trainer as TR
+from repro.kernels import ops as KOPS
+from repro.optim import adamw
+
+CFG = COSTMODEL_BASE
+MAX_BUCKET = CFG.max_seq        # the serving ladder's largest bucket
+MAX_BATCH = 256                 # CostModelService's default max_batch
+DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a described-chip compile is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache off here
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _abstract(tree, sharding, dtype=None):
+    """ShapeDtypeStructs on the described chip; floating leaves take
+    ``dtype`` when given (the service's bf16 cast)."""
+    def leaf(a):
+        dt = a.dtype
+        if dtype is not None and jnp.issubdtype(dt, jnp.floating):
+            dt = dtype
+        return jax.ShapeDtypeStruct(a.shape, dt, sharding=sharding)
+    return jax.tree.map(leaf, tree)
+
+
+def _params(kind):
+    init = CM.get_model(kind)[0]
+    return jax.eval_shape(
+        lambda: init(jax.random.PRNGKey(0), CFG, heads=CM.DEFAULT_HEADS))
+
+
+def _ids(sharding, batch=MAX_BATCH, seq=MAX_BUCKET):
+    return jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=sharding)
+
+
+@pytest.mark.parametrize("kind,forward", [
+    ("conv1d", KOPS.conv_forward_apply),
+    ("lstm", KOPS.lstm_forward_apply)])
+@pytest.mark.parametrize("dtype", sorted(DTYPES))
+def test_kernel_forward_compiles_for_v5e(one_chip, kind, forward, dtype):
+    """The use_kernel serving forward (conv: XLA gather + fused tower/FC/
+    heads kernel; lstm: XLA projection + VMEM-carry recurrence kernel,
+    hidden 128) at COSTMODEL_BASE widths on the largest bucket."""
+    params = _abstract(_params(kind), one_chip, DTYPES[dtype])
+    compiled = jax.jit(
+        lambda p, ids: forward(p, ids, interpret=False)).lower(
+            params, _ids(one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_lstm_kernel_hidden_128(one_chip):
+    """The recurrence kernel alone at hidden 128, the BASE width."""
+    from repro.kernels.lstm_scan import lstm_scan_fused
+    H = CFG.lstm_hidden
+    assert H == 128
+    xw = jax.ShapeDtypeStruct((64, MAX_BUCKET, 4 * H), jnp.float32,
+                              sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((64, MAX_BUCKET), jnp.float32,
+                                sharding=one_chip)
+    wh = jax.ShapeDtypeStruct((H, 4 * H), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda a, m, w: lstm_scan_fused(a, m, w)).lower(
+        xw, mask, wh).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kind", ["conv1d", "lstm"])
+def test_xla_serving_forward_compiles_for_v5e(one_chip, kind):
+    """The plain-XLA serving apply (use_kernel=False) at BASE widths."""
+    apply_fn = CM.get_model(kind)[1]
+    params = _abstract(_params(kind), one_chip)
+    compiled = jax.jit(apply_fn).lower(params, _ids(one_chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis() is not None
+
+
+def test_train_step_compiles_for_v5e(one_chip):
+    """One COSTMODEL_BASE TrainEngine step (the engine's own program)
+    for conv1d, three joint heads, batch 64 at the 128 bucket."""
+    _, apply_fn, _ = CM.get_model("conv1d")
+    params = _params("conv1d")
+    state = _abstract(
+        (params, jax.eval_shape(adamw.init_state, params), None), one_chip)
+    opt_cfg = adamw.AdamWConfig(lr=1e-3, total_steps=40, warmup_steps=4)
+    step = TR.make_train_step(apply_fn, opt_cfg, CM.DEFAULT_HEADS)
+    y = jax.ShapeDtypeStruct((64, len(CM.DEFAULT_HEADS)), np.float32,
+                             sharding=one_chip)
+    compiled = step.lower(state, _ids(one_chip, 64, 128), y).compile()
+    assert compiled.memory_analysis() is not None

@@ -175,3 +175,59 @@ def test_train_result_stats_populated(split):
     assert res.stats["steps_per_s"] > 0
     assert np.isfinite(res.stats["final_loss"])
     assert res.history and res.history[-1][0] == 30
+
+
+# ------------------------------------------------------- data parallelism
+_DP_SCRIPT = """
+import json, sys
+import numpy as np
+from repro.configs import COSTMODEL_SMALL
+from repro.core import models as CM
+from repro.core import trainer as TR
+from repro.ir import dataset as DS
+
+ds = DS.build_dataset(200, mode="ops", max_seq=64, vocab_size=512,
+                      augment_factor=1, seed=3)
+out = {}
+for md in (4, 1):
+    eng = TR.TrainEngine(sys.argv[1], COSTMODEL_SMALL, CM.DEFAULT_HEADS,
+                         steps=6, batch_size=16, log_every=1, mesh_data=md)
+    res = eng.fit(ds)
+    out[md] = [loss for _, loss in res.history]
+    if md == 4:
+        b = eng.place_batch({"ids": np.zeros((16, 64), np.int32),
+                             "y": np.zeros((16, 3), np.float32)})
+        out["spec"] = str(b["ids"].sharding.spec)
+        out["ids_shards"] = sorted(
+            {tuple(s.data.shape) for s in b["ids"].addressable_shards})
+        out["y_shards"] = sorted(
+            {tuple(s.data.shape) for s in b["y"].addressable_shards})
+print(json.dumps(out))
+"""
+
+
+@pytest.mark.parametrize("kind", ["conv1d", "lstm"])
+def test_engine_data_parallel_shards_batch_and_matches(kind):
+    """mesh_data=4 on 4 virtual CPU devices: the batch is split along
+    the mesh's data axis (one quarter per device), and the per-step
+    losses match mesh_data=1 on the same seed and batches. Runs in a
+    child process: the device count is fixed when JAX starts."""
+    import json
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (src, env.get("PYTHONPATH")) if p)
+    proc = subprocess.run([sys.executable, "-c", _DP_SCRIPT, kind],
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["spec"] == "PartitionSpec('data',)"
+    assert out["ids_shards"] == [[4, 64]]
+    assert out["y_shards"] == [[4, 3]]
+    assert len(out["4"]) == len(out["1"]) == 6
+    np.testing.assert_allclose(out["4"], out["1"], rtol=1e-5, atol=1e-6)
