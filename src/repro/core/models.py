@@ -98,8 +98,12 @@ def fc_scalar_head(p, feats):
 
 def fc_finish(p, x):
     """Pooled features -> fc_stack -> head outputs, for either layout
-    (shared by conv_apply and the fused-kernel tower in kernels/ops.py)."""
-    return _finish(p, fc_stack(p, x), lambda f: fc_scalar_head(p, f))
+    (shared by conv_apply and the fused-kernel tower in kernels/ops.py),
+    under the ``fc`` and ``heads`` named scopes."""
+    with jax.named_scope("fc"):
+        feats = fc_stack(p, x)
+    with jax.named_scope("heads"):
+        return _finish(p, feats, lambda f: fc_scalar_head(p, f))
 
 
 # --------------------------------------------------------------- FC (BoT)
@@ -246,12 +250,20 @@ def conv_encode(p, ids, *, pooled_only: bool = False):
 
     ``pooled_only`` stops after the max-pool (the kernel module's seam).
     The mask follows the embedding dtype (lax.conv is strict about
-    matching dtypes), so bf16-cast params run a bf16 tower."""
-    x = p["emb"][ids] * _mask(ids).astype(p["emb"].dtype)[..., None]
-    for layer in p["convs"]:
-        x = jax.nn.relu(conv1d(x, layer["w"], layer["b"]))
-    x = x.max(axis=1)                            # MaxPool1D over sequence
-    return x if pooled_only else fc_stack(p, x)
+    matching dtypes), so bf16-cast params run a bf16 tower. Each stage
+    runs under a named scope (``embed``, ``conv{i}``, ``pool``, ``fc``)
+    that a profile's op metadata carries."""
+    with jax.named_scope("embed"):
+        x = p["emb"][ids] * _mask(ids).astype(p["emb"].dtype)[..., None]
+    for i, layer in enumerate(p["convs"]):
+        with jax.named_scope(f"conv{i}"):
+            x = jax.nn.relu(conv1d(x, layer["w"], layer["b"]))
+    with jax.named_scope("pool"):
+        x = x.max(axis=1)                        # MaxPool1D over sequence
+    if pooled_only:
+        return x
+    with jax.named_scope("fc"):
+        return fc_stack(p, x)
 
 
 def conv_apply(p, ids, *, pooled_feats: bool = False):

@@ -31,7 +31,12 @@ turns that into a coalescing pipeline:
   (bucket x ladder-batch) jitted program so no client ever pays
   first-call XLA compile latency.
 * **streaming metrics** — queue depth, batch occupancy, request
-  latency percentiles (p50/p95/p99), cache hit rate, shed count.
+  latency percentiles (p50/p95/p99), cache hit rate, shed count, and
+  the summed queue wait of every dispatched request.
+* **profiler spans** — while a profile is recorded, ``submit``,
+  ``predict_all`` and the worker mark their layers on the profiler's
+  clock (``repro.obs.trace.annotate``: ``client.predict_all``,
+  ``server.featurize``/``wait``/``dispatch``/``collect``/``resolve``).
 
 The server duck-types the service's prediction API (``predict_all``,
 ``predict_graphs``, ``predict``, ``resolve_target``, ``heads``), so the
@@ -50,6 +55,7 @@ import numpy as np
 
 from repro.core.service import CostModelService
 from repro.ir.graph import Graph
+from repro.obs.trace import annotate
 
 
 class ServerOverloadedError(RuntimeError):
@@ -85,7 +91,7 @@ class ServerMetrics:
     queue Condition on ``self._lock``) guards every field, but the
     submit hot path never takes it twice: ``note_request`` is called by
     submit while it already holds the queue lock, while the worker-side
-    methods (count, observe_latencies) and snapshot() acquire it
+    methods (note_batch, observe_latencies) and snapshot() acquire it
     themselves."""
 
     def __init__(self, reservoir: int = 8192):
@@ -110,15 +116,30 @@ class ServerMetrics:
         self.full_flushes = 0
         self.stagnant_flushes = 0  # arrivals stalled; flushed early
         self.pipeline_flushes = 0  # dispatched behind an in-flight batch
+        # every queued request the worker took off a queue (a failed
+        # dispatch too), and the sum of their waits from submit to
+        # dispatch: unsampled, so queue_wait_s / dispatched is the mean
+        # queue wait of all requests
+        self.dispatched = 0
+        self.queue_wait_s = 0.0
         self._lat_us = deque(maxlen=reservoir)
 
     def observe_latencies(self, us: Sequence[float]) -> None:
         with self._lock:
             self._lat_us.extend(us)
 
-    def count(self, field: str, n: int = 1) -> None:
+    def note_batch(self, path: Optional[str], n: int,
+                   wait_s: float) -> None:
+        """Worker-side bumps for one batch taken off a queue, in one
+        lock round; ``path`` is None when its dispatch failed."""
         with self._lock:
-            setattr(self, field, getattr(self, field) + n)
+            self.dispatched += n
+            self.queue_wait_s += wait_s
+            if path is not None:
+                setattr(self, f"{path}_flushes",
+                        getattr(self, f"{path}_flushes") + 1)
+                self.batches += 1
+                self.batched_entries += n
 
     def note_request(self, cache_hit: bool = False, shed: bool = False,
                      coalesced: bool = False, queue_depth: int = 0) -> None:
@@ -153,6 +174,8 @@ class ServerMetrics:
                 "full_flushes": self.full_flushes,
                 "stagnant_flushes": self.stagnant_flushes,
                 "pipeline_flushes": self.pipeline_flushes,
+                "dispatched": self.dispatched,
+                "queue_wait_s": self.queue_wait_s,
                 "queue_depth": queue_depth,
                 "max_queue_depth": self.max_queue_depth,
             }
@@ -227,6 +250,7 @@ class CostModelServer:
         self._work = threading.Condition(self._lock)
         self._running = False
         self._worker: Optional[threading.Thread] = None
+        self._n_dispatched = 0                  # batch sequence number
 
     # ------------------------------------------------------------ lifecycle
     def start(self, warmup: bool = True) -> "CostModelServer":
@@ -285,15 +309,19 @@ class CostModelServer:
         queue sheds the request instead."""
         if not self._running:
             raise RuntimeError("server not started (call start())")
-        if self.service.fast_encode:
-            key = self.service.key_of(g)
-            hit = self.service.cache_lookup(key)
-            ids = None if hit is not None else self.service.ids_for(g, key)
-        else:
-            key, ids = self.service.entry(g)
-            hit = self.service.cache_lookup(key)
-            if hit is not None:
-                ids = None
+        with annotate("server.featurize") as sp:
+            if self.service.fast_encode:
+                key = self.service.key_of(g)
+                hit = self.service.cache_lookup(key)
+                ids = None if hit is not None else \
+                    self.service.ids_for(g, key)
+            else:
+                key, ids = self.service.entry(g)
+                hit = self.service.cache_lookup(key)
+                if hit is not None:
+                    ids = None
+            if sp is not None:
+                sp.set_metadata(hit=int(hit is not None))
         return self._submit_resolved(key, ids, hit, trace=trace)
 
     def submit_entry(self, key: str, ids: np.ndarray, *,
@@ -539,21 +567,31 @@ class CostModelServer:
 
     def _dispatch(self, batch: List[_Request], path: str):
         t_disp = time.monotonic()
+        seq = self._n_dispatched = self._n_dispatched + 1
+        wait_s = sum(t_disp - r.t_submit for r in batch)
         entries = [(r.key, r.ids) for r in batch]
-        try:
-            handle = self.service.forward_entries_dispatch(entries)
-        except Exception as e:          # resolve waiters, don't kill worker
-            return ("err", e, t_disp, path)
-        self.metrics.count(f"{path}_flushes")
-        self.metrics.count("batches")
-        self.metrics.count("batched_entries", len(batch))
-        return ("ok", handle, t_disp, path)
+        with annotate("server.dispatch") as sp:
+            if sp is not None:          # batch[0] waited longest (FIFO)
+                sp.set_metadata(
+                    batch=seq, rows=len(batch),
+                    ladder_rows=self.service._ladder_batch(len(batch)),
+                    width=len(batch[0].ids), path=path,
+                    wait_us=wait_s * 1e6,
+                    oldest_wait_us=(t_disp - batch[0].t_submit) * 1e6)
+            try:
+                handle = self.service.forward_entries_dispatch(entries)
+            except Exception as e:      # resolve waiters, don't kill worker
+                self.metrics.note_batch(None, len(batch), wait_s)
+                return ("err", e, t_disp, path, seq)
+        self.metrics.note_batch(path, len(batch), wait_s)
+        return ("ok", handle, t_disp, path, seq)
 
     def _collect_resolve(self, item: Tuple[List[_Request], Any]) -> None:
-        batch, (status, payload, t_disp, path) = item
+        batch, (status, payload, t_disp, path, seq) = item
         if status == "ok":
             try:
-                rows = self.service.forward_entries_collect(payload)
+                with annotate("server.collect", batch=seq):
+                    rows = self.service.forward_entries_collect(payload)
                 err = None
             except Exception as e:
                 rows, err = None, e
@@ -564,26 +602,36 @@ class CostModelServer:
             self._n_pending -= sum(len(ws) for ws in waiters)
         now = time.monotonic()
         tr = self.tracer
+        # ring spans start when their work did: monotonic -> wall clock
+        to_wall = time.time() - now
         lats = []
-        for i, ws in enumerate(waiters):
-            for j, w in enumerate(ws):
-                if tr is not None and w.trace is not None:
-                    # retroactive spans: the request's queue wait and the
-                    # batch it rode are only known here. Emitted BEFORE
-                    # set_result so a callback on the future (the replica
-                    # loop shipping spans back) already sees them.
-                    tr.emit("server.queue", w.trace,
-                            max(t_disp - w.t_submit, 0.0),
-                            tags={"coalesced": int(j > 0)})
-                    tr.emit("server.forward", w.trace,
-                            max(now - t_disp, 0.0),
-                            status="ok" if err is None else "err",
-                            tags={"batch_size": len(batch), "path": path})
-                if err is not None:
-                    w.future.set_exception(err)
-                else:
-                    lats.append((now - w.t_submit) * 1e6)
-                    w.future.set_result(rows[i])
+        with annotate("server.resolve") as sp:
+            if sp is not None:
+                sp.set_metadata(batch=seq,
+                                waiters=sum(len(ws) for ws in waiters))
+            for i, ws in enumerate(waiters):
+                for j, w in enumerate(ws):
+                    if tr is not None and w.trace is not None:
+                        # retroactive spans: the request's queue wait and
+                        # the batch it rode are only known here. Emitted
+                        # BEFORE set_result so a callback on the future
+                        # (the replica loop shipping spans back) already
+                        # sees them.
+                        tr.emit("server.queue", w.trace,
+                                max(t_disp - w.t_submit, 0.0),
+                                t_wall=w.t_submit + to_wall,
+                                tags={"coalesced": int(j > 0)})
+                        tr.emit("server.forward", w.trace,
+                                max(now - t_disp, 0.0),
+                                t_wall=t_disp + to_wall,
+                                status="ok" if err is None else "err",
+                                tags={"batch_size": len(batch),
+                                      "path": path})
+                    if err is not None:
+                        w.future.set_exception(err)
+                    else:
+                        lats.append((now - w.t_submit) * 1e6)
+                        w.future.set_result(rows[i])
         if lats:
             self.metrics.observe_latencies(lats)
 
@@ -603,31 +651,30 @@ class CostModelServer:
         graphs coalesce into shared forward passes."""
         if not graphs:
             return {t: np.zeros((0,), np.float32) for t in self.heads}
-        tr = self.tracer
-        root = None
-        if tr is not None:
-            ctx = tr.sample()          # head decision: 1 in sample_every
-            root = tr.start("client.predict_all", ctx,
-                            tags={"n_graphs": len(graphs)})
-        sub = root.ctx if root is not None else None
-        try:
-            if len(graphs) == 1:       # compiler hot path: one candidate
-                raw = self.submit(graphs[0], trace=sub).result(
-                    timeout=timeout)[None]
-            else:
-                futs = [self.submit(g, trace=sub) for g in graphs]
-                raw = np.stack([f.result(timeout=timeout) for f in futs])
-        except BaseException:
+        with annotate("client.predict_all", n_graphs=len(graphs)):
+            tr = self.tracer
+            root = None
             if tr is not None:
-                tr.end(root, status="err")
-            raise
-        if tr is not None:
-            tr.end(root)
-        out = self.service.denormalize_rows(raw)
-        drift = getattr(self.service, "drift", None)
-        if drift is not None:
-            drift.observe_batch(graphs, out)
-        return out
+                ctx = tr.sample()      # head decision: 1 in sample_every
+                root = tr.start("client.predict_all", ctx,
+                                tags={"n_graphs": len(graphs)})
+            sub = root.ctx if root is not None else None
+            try:
+                futs = [self.submit(g, trace=sub) for g in graphs]
+                with annotate("server.wait"):
+                    raw = np.stack([f.result(timeout=timeout)
+                                    for f in futs])
+            except BaseException:
+                if tr is not None:
+                    tr.end(root, status="err")
+                raise
+            if tr is not None:
+                tr.end(root)
+            out = self.service.denormalize_rows(raw)
+            drift = getattr(self.service, "drift", None)
+            if drift is not None:
+                drift.observe_batch(graphs, out)
+            return out
 
     def predict_graphs(self, graphs: Sequence[Graph],
                        target: Optional[str] = None) -> np.ndarray:

@@ -170,13 +170,19 @@ class CostModelService:
                     if jnp.issubdtype(a.dtype, jnp.floating) else a
             params = jax.tree.map(_cast, params)
         n_bytes = sum(np.asarray(x).nbytes for x in jax.tree.leaves(params))
+        # Either way the program is named ``serve_forward`` (its XLA
+        # module ``jit_serve_forward``), so a profile finds it.
         if n_bytes <= 16 * 2**20:
-            self._jit = jax.jit(lambda ids: apply_fn(params, ids))
+            def serve_forward(ids):
+                return apply_fn(params, ids)
+            self._jit = jax.jit(serve_forward)
             self._jit_args = ()
             self._apply = self._jit
         else:
+            def serve_forward(params, ids):
+                return apply_fn(params, ids)
             dev_params = jax.device_put(params)
-            jitted = self._jit = jax.jit(apply_fn)
+            jitted = self._jit = jax.jit(serve_forward)
             self._jit_args = (dev_params,)
             self._apply = lambda ids: jitted(dev_params, ids)
         self.heads = CM.model_heads(self.params) or (
@@ -188,10 +194,14 @@ class CostModelService:
                                     if b <= self.max_seq)) or (self.max_seq,)
         self._pad_slack = pad_slack(self.kind, self.cfg)
         if self.batch_ladder is None:
-            # powers of two plus midpoints (1,2,3,4,6,8,12,...): padding
-            # waste stays under 33% at any coalesced-batch occupancy
+            # powers of two plus midpoints (2,3,4,6,8,12,...): padding
+            # waste stays under 33% at any coalesced-batch occupancy but a
+            # lone row's. No one-row programs: XLA runs a one-row batch
+            # through another kernel (a matrix-vector product on the CPU)
+            # whose sums round differently, and a row's prediction must
+            # not depend on how requests were packed
             ladder = set()
-            b = 1
+            b = 1 if self.max_batch == 1 else 2
             while b < self.max_batch:
                 ladder.add(b)
                 if b * 3 // 2 < self.max_batch:

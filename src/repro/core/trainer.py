@@ -43,6 +43,7 @@ from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.core import models as CM
 from repro.data import pipeline as PIPE
 from repro.ir import dataset as DS
+from repro.obs.trace import OFF, annotate, annotate_step, profiling
 from repro.optim import adamw, compress
 from repro.runtime import fault
 from repro.runtime.sharding import ShardingRules, tree_shardings
@@ -243,9 +244,19 @@ class TrainEngine:
         last = [jnp.float32(np.nan)]
 
         def step_fn(state, step):
-            batch = next(it)
-            batch = self.place_batch(batch)
-            state, loss = train_step(state, batch["ids"], batch["y"])
+            # profiler spans: the loader, the host->device copy, the step
+            with annotate("train.batch"):
+                batch = next(it)
+            with annotate("train.place"):
+                placed = self.place_batch(batch)
+            span = OFF
+            if profiling():
+                ids = batch["ids"]
+                span = annotate_step(   # tokens: non-PAD (id 0) positions
+                    "train.step", step, rows=ids.shape[0],
+                    width=ids.shape[1], tokens=int(np.count_nonzero(ids)))
+            with span:
+                state, loss = train_step(state, placed["ids"], placed["y"])
             last[0] = loss     # device value; sync only at log points
             return state
 

@@ -30,6 +30,19 @@ request's queue wait only at dispatch time). Finished spans land in a
 bounded ring-buffer :class:`TraceRecorder`; exporters drain it, the
 replica wire path ``take``s spans per trace id to ship them back to
 the client with the response.
+
+Profiler-clock spans. The ring's clocks never reach a profiler trace,
+so they cannot say what the host was doing while the device sat idle.
+:func:`annotate` opens a ``jax.profiler.TraceAnnotation`` only while a
+profile is being recorded, and otherwise returns one shared no-op, so
+with the profiler off an unsampled layer boundary builds neither an
+annotation nor its arguments: it costs one ``is_enabled`` check and
+the ``with`` statement. The program's layers wrap their work in it
+(``search.beam``, ``client.predict_all``, ``server.*``, ``train.*``;
+``docs/observability.md`` has the table), and :meth:`Tracer.span` does
+too, so a sampled request's live spans land in the profile as well.
+Retroactive :meth:`Tracer.emit` spans stay in the ring only: what they
+carry reaches the profile as arguments of the live span that knows it.
 """
 from __future__ import annotations
 
@@ -51,6 +64,60 @@ _ID_COUNTER = itertools.count(1)
 
 def _new_id() -> str:
     return f"{_ID_PREFIX}{next(_ID_COUNTER):06x}"
+
+
+# ------------------------------------------------- profiler-clock spans
+class _Off:
+    """The span of a boundary while no profile is recorded. ``as`` binds
+    None, so a caller adds late arguments with ``if sp is not None:
+    sp.set_metadata(...)``."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+OFF = _Off()
+_profiler = None
+
+
+def _jax_profiler():
+    # imported on first use: the ring, the wire and the exporters need
+    # no JAX, and by then the caller has imported it anyway
+    global _profiler
+    if _profiler is None:
+        import jax.profiler
+        _profiler = jax.profiler
+    return _profiler
+
+
+def profiling() -> bool:
+    """True while a profiler trace is being recorded. A caller whose
+    span arguments cost anything to compute checks this first."""
+    return _jax_profiler().TraceAnnotation.is_enabled()
+
+
+def annotate(name: str, **args):
+    """Context manager: a ``jax.profiler.TraceAnnotation`` named
+    ``name`` with ``args`` as its event stats while a profile is being
+    recorded, else the shared no-op :data:`OFF`."""
+    p = _profiler or _jax_profiler()
+    if not p.TraceAnnotation.is_enabled():
+        return OFF
+    return p.TraceAnnotation(name, **args)
+
+
+def annotate_step(name: str, step_num: int, **args):
+    """:func:`annotate` for one step of a loop, as a
+    ``jax.profiler.StepTraceAnnotation`` (the profiler's step marker)."""
+    p = _profiler or _jax_profiler()
+    if not p.TraceAnnotation.is_enabled():
+        return OFF
+    return p.StepTraceAnnotation(name, step_num=step_num, **args)
 
 
 class TraceContext:
@@ -228,11 +295,12 @@ class Tracer:
         None when untraced) so callers can add tags / derive child
         contexts. Exceptions mark the span ``err`` and re-raise."""
         sp = self.start(name, ctx, tags)
-        try:
-            yield sp
-        except BaseException:
-            self.end(sp, status="err")
-            raise
+        with annotate(name) if sp is not None else OFF:
+            try:
+                yield sp
+            except BaseException:
+                self.end(sp, status="err")
+                raise
         self.end(sp)
 
     def emit(self, name: str, ctx: Optional[TraceContext], dur_s: float,

@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.ir.graph import Graph
+from repro.obs.trace import annotate
 from repro.opt import rewrites as RW
 
 
@@ -185,61 +186,62 @@ def beam_search(service, g: Graph,
     belong to weight-normalized single-rule searches (UnrollAdvisor);
     ``preserve_outputs=False`` admits them here too.
     """
-    rules = list(rules) if rules is not None else RW.default_rules()
-    if preserve_outputs:
-        rules = [r for r in rules if r.preserves_outputs]
-    if greedy:
-        beam_width = 1
-    obj = (objective or Objective()).bind(service)
-    preds0 = service.predict_all([g])
-    root_row = {t: float(v[0]) for t, v in preds0.items()}
-    root_score = float(obj.scores(preds0)[0])
-    root = _State(g, g.struct_key(), [], root_score, root_row)
-    visited = {root.key}
-    best = root
-    frontier = [root]
-    res = SearchResult(root=g, best=g, best_seq=[], root_score=root_score,
-                       best_score=root_score, root_preds=root_row,
-                       best_preds=root_row, predict_calls=1)
-    if record_candidates:
-        res.candidates = [(g, root_row[obj.lat_t])]
-    for _ in range(max_steps):
-        cap = min(max_candidates, eval_budget - res.evaluated)
-        batch = _expand_lazy(frontier, rules, visited, cap) \
-            if cap > 0 else []
-        if not batch:
-            break
-        # only candidates actually costed become visited: states dropped
-        # by the cap stay re-derivable by a later (affordable) expansion
-        visited.update(c[4] for c in batch)
-        # THE one batched model query of this frontier expansion
-        preds = service.predict_all([c[3] for c in batch])
-        res.predict_calls += 1
-        res.expansions += 1
-        res.evaluated += len(batch)
-        scores = obj.scores(preds)
-        states = []
-        for i, (parent, rname, site, ng, key) in enumerate(batch):
-            row = {t: float(v[i]) for t, v in preds.items()}
-            states.append(_State(ng, key, parent.seq + [(rname, site)],
-                                 float(scores[i]), row))
-            if res.candidates is not None:
-                res.candidates.append((ng, row[obj.lat_t]))
-        states.sort(key=lambda s: s.score)
-        res.trace.append({"candidates": len(batch),
-                          "best_score": states[0].score})
-        if states[0].score < best.score:
-            best = states[0]
-        if greedy and states[0].score >= frontier[0].score:
-            break
-        frontier = states[:beam_width]
-        if res.evaluated >= eval_budget:
-            break
-    res.best = best.graph
-    res.best_seq = best.seq
-    res.best_score = best.score
-    res.best_preds = best.preds
-    return res
+    with annotate("search.beam"):
+        rules = list(rules) if rules is not None else RW.default_rules()
+        if preserve_outputs:
+            rules = [r for r in rules if r.preserves_outputs]
+        if greedy:
+            beam_width = 1
+        obj = (objective or Objective()).bind(service)
+        preds0 = service.predict_all([g])
+        root_row = {t: float(v[0]) for t, v in preds0.items()}
+        root_score = float(obj.scores(preds0)[0])
+        root = _State(g, g.struct_key(), [], root_score, root_row)
+        visited = {root.key}
+        best = root
+        frontier = [root]
+        res = SearchResult(root=g, best=g, best_seq=[], root_score=root_score,
+                           best_score=root_score, root_preds=root_row,
+                           best_preds=root_row, predict_calls=1)
+        if record_candidates:
+            res.candidates = [(g, root_row[obj.lat_t])]
+        for _ in range(max_steps):
+            cap = min(max_candidates, eval_budget - res.evaluated)
+            batch = _expand_lazy(frontier, rules, visited, cap) \
+                if cap > 0 else []
+            if not batch:
+                break
+            # only candidates actually costed become visited: states dropped
+            # by the cap stay re-derivable by a later (affordable) expansion
+            visited.update(c[4] for c in batch)
+            # THE one batched model query of this frontier expansion
+            preds = service.predict_all([c[3] for c in batch])
+            res.predict_calls += 1
+            res.expansions += 1
+            res.evaluated += len(batch)
+            scores = obj.scores(preds)
+            states = []
+            for i, (parent, rname, site, ng, key) in enumerate(batch):
+                row = {t: float(v[i]) for t, v in preds.items()}
+                states.append(_State(ng, key, parent.seq + [(rname, site)],
+                                     float(scores[i]), row))
+                if res.candidates is not None:
+                    res.candidates.append((ng, row[obj.lat_t]))
+            states.sort(key=lambda s: s.score)
+            res.trace.append({"candidates": len(batch),
+                              "best_score": states[0].score})
+            if states[0].score < best.score:
+                best = states[0]
+            if greedy and states[0].score >= frontier[0].score:
+                break
+            frontier = states[:beam_width]
+            if res.evaluated >= eval_budget:
+                break
+        res.best = best.graph
+        res.best_seq = best.seq
+        res.best_score = best.score
+        res.best_preds = best.preds
+        return res
 
 
 def greedy_search(service, g: Graph,

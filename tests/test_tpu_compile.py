@@ -14,6 +14,7 @@ process at a time may load the TPU library, and pytest-xdist workers
 all import every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -121,7 +122,9 @@ def test_xla_serving_forward_compiles_for_v5e(one_chip, kind):
 
 def test_train_step_compiles_for_v5e(one_chip):
     """One COSTMODEL_BASE TrainEngine step (the engine's own program)
-    for conv1d, three joint heads, batch 64 at the 128 bucket."""
+    for conv1d, three joint heads, batch 64 at the 128 bucket. Its op
+    metadata carries the step's name and the model's named scopes, which
+    is how a profile of the chip attributes device time."""
     _, apply_fn, _ = CM.get_model("conv1d")
     params = _params("conv1d")
     state = _abstract(
@@ -132,3 +135,8 @@ def test_train_step_compiles_for_v5e(one_chip):
                              sharding=one_chip)
     compiled = step.lower(state, _ids(one_chip, 64, 128), y).compile()
     assert compiled.memory_analysis() is not None
+    ops = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    scopes = [f"conv{i}" for i in range(len(CFG.conv_channels))]
+    for name in [*scopes, "pool", "heads"]:     # jvp(conv0)/..., etc.
+        assert any(re.match(rf"jit\(train_step\)/.*\b{name}\b", o)
+                   for o in ops), name
