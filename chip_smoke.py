@@ -319,13 +319,16 @@ def four_chips(seed):
             batch = engine.place_batch(
                 next(iter(engine.make_loader(train, y))))
             for k, v in batch.items():
-                shards = [(str(s.device), tuple(s.data.shape))
-                          for s in v.addressable_shards]
-                log(f"dp batch {k} {v.shape} spec {v.sharding.spec}: "
-                    f"{shards}")
-                check(v.sharding.spec[0] == "data"
-                      and all(s[1][0] == v.shape[0] // 4 for s in shards),
-                      f"batch {k} is not split along data: {shards}")
+                # a batch split into width-sorted chunks: each chunk
+                for a in getattr(v, "parts", (v,)):
+                    shards = [(str(s.device), tuple(s.data.shape))
+                              for s in a.addressable_shards]
+                    log(f"dp batch {k} {a.shape} spec {a.sharding.spec}: "
+                        f"{shards}")
+                    check(a.sharding.spec[0] == "data"
+                          and all(s[1][0] == a.shape[0] // 4
+                                  for s in shards),
+                          f"batch {k} is not split along data: {shards}")
     rel = np.abs(losses[4] - losses[1]) / np.abs(losses[1])
     log(f"dp losses 4 vs 1 device: max rel diff {rel.max():.3e} "
         f"(rtol {DP_LOSS_RTOL})")

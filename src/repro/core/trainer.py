@@ -11,6 +11,14 @@ full substrate every time:
   power-of-two sequence bucket (the same ladder serving uses, including the
   conv1d pad-slack rule), so each train step jits one program per bucket
   instead of padding every batch to the global ``max_seq``;
+* width-sorted chunks: a batch whose rows span several buckets arrives
+  as :class:`~repro.data.pipeline.Chunks` (rows sorted by bucket, four
+  sub-batches each at its own widest bucket). The step runs one gradient
+  program per chunk shape, each giving its row-weighted gradient and
+  loss, then one program that sums them and compresses, clips and
+  applies AdamW: the whole batch's update over fewer pad positions.
+  Every chunk shape is compiled before step 1. A batch the loader
+  leaves whole runs the one fused ``train_step`` program;
 * mesh + :class:`~repro.runtime.sharding.ShardingRules` (params are placed
   by the per-family logical axis tables when the mesh has >1 device);
 * optional int8 error-feedback gradient compression on the DP axis;
@@ -33,6 +41,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
 import jax
@@ -74,25 +83,82 @@ def make_loss_fn(apply_fn, heads: Optional[Tuple[str, ...]] = None):
     return loss_fn
 
 
-def make_train_step(apply_fn, opt_cfg,
-                    heads: Optional[Tuple[str, ...]] = None):
-    """The engine's jitted step: ``(state, ids, y) -> (state, loss)``
-    with ``state = (params, opt_state, err)``. ``err`` is the int8
-    error-feedback state of gradient compression, or None when
-    compression is off. Public so a compile test can build the exact
-    program the engine runs."""
-    loss_fn = make_loss_fn(apply_fn, heads)
+def _update(opt_cfg, state, grads):
+    params, opt_state, err = state
+    if err is not None:
+        grads, err = compress.compress_grads(grads, err)
+    params, opt_state, _ = adamw.apply_updates(
+        params, grads, opt_state, opt_cfg)
+    return params, opt_state, err
 
-    @jax.jit
-    def train_step(state, ids, yy):
-        params, opt_state, err = state
-        loss, grads = jax.value_and_grad(loss_fn)(params, ids, yy)
-        if err is not None:
-            grads, err = compress.compress_grads(grads, err)
-        params, opt_state, _ = adamw.apply_updates(
-            params, grads, opt_state, opt_cfg)
-        return (params, opt_state, err), loss
-    return train_step
+
+# The programs are module-level with the model and optimizer as static
+# arguments, so every step built for the same model shares their
+# compiled executables: the engine compiles the chunk programs before
+# step 1 and the step it hands out finds them.
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def train_step(apply_fn, opt_cfg, heads, state, ids, yy):
+    """One whole batch: gradient, compression, clipping, AdamW."""
+    loss, grads = jax.value_and_grad(make_loss_fn(apply_fn, heads))(
+        state[0], ids, yy)
+    return _update(opt_cfg, state, grads), loss
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def chunk_grad(apply_fn, heads, params, ids, yy, start):
+    """One chunk's float32 gradient and loss, each weighted by the
+    chunk's share of the batch's rows; the chunk's labels are
+    ``yy[start:start + rows]``."""
+    rows = ids.shape[0]
+    y = jax.lax.dynamic_slice_in_dim(yy, start, rows)
+    loss, grads = jax.value_and_grad(make_loss_fn(apply_fn, heads))(
+        params, ids, y)
+    w = rows / yy.shape[0]
+    return (jax.tree.map(lambda g: w * g.astype(jnp.float32), grads),
+            w * loss)
+
+
+@partial(jax.jit, static_argnums=0)
+def apply_grads(opt_cfg, state, chunks):
+    """The whole batch's update and loss from its chunks' weighted
+    gradients and losses, summed here."""
+    grads = jax.tree.map(lambda *g: sum(g), *[g for g, _ in chunks])
+    return _update(opt_cfg, state, grads), sum(loss for _, loss in chunks)
+
+
+class TrainStep:
+    """The engine's step: ``(state, ids, y) -> (state, loss)`` with
+    ``state = (params, opt_state, err)``. ``err`` is the int8
+    error-feedback state of gradient compression, or None when
+    compression is off. ``ids`` is an array (one ``train_step``
+    program) or :class:`~repro.data.pipeline.Chunks` (a ``chunk_grad``
+    program a chunk, then ``apply_grads``); ``y`` is one array in the
+    batch's row order."""
+
+    def __init__(self, apply_fn, opt_cfg, heads=None):
+        self.static = (apply_fn, opt_cfg, heads)
+
+    def __call__(self, state, ids, yy):
+        apply_fn, opt_cfg, heads = self.static
+        if not isinstance(ids, PIPE.Chunks):
+            return train_step(apply_fn, opt_cfg, heads, state, ids, yy)
+        chunks, start = [], 0
+        for part in ids.parts:
+            chunks.append(chunk_grad(apply_fn, heads, state[0], part, yy,
+                                     start))
+            start += part.shape[0]
+        return apply_grads(opt_cfg, state, tuple(chunks))
+
+    def lower(self, state, ids, yy):
+        """The whole-batch program, lowered (for compile tests)."""
+        return train_step.lower(*self.static, state, ids, yy)
+
+
+def make_train_step(apply_fn, opt_cfg,
+                    heads: Optional[Tuple[str, ...]] = None) -> TrainStep:
+    """The engine's step (:class:`TrainStep`). Public so a compile test
+    can build the exact programs the engine runs."""
+    return TrainStep(apply_fn, opt_cfg, heads)
 
 
 @dataclass(frozen=True)
@@ -178,19 +244,43 @@ class TrainEngine:
                            drop_remainder=e.drop_remainder,
                            prefetch=e.prefetch,
                            bucket_by=self.bucket_assignments(train),
-                           bucket_mode=e.bucket_mode)
+                           bucket_mode=e.bucket_mode,
+                           chunk_multiple=e.mesh_data)
 
-    def place_batch(self, batch: Dict[str, np.ndarray]
-                    ) -> Dict[str, jax.Array]:
-        """Host batch -> device arrays. On a multi-device mesh (set by
+    def place_batch(self, batch: Dict[str, Any]) -> Dict[str, Any]:
+        """Host batch -> device arrays (a :class:`~repro.data.pipeline.
+        Chunks` chunk by chunk). On a multi-device mesh (set by
         :meth:`fit`) every array is split along the mesh's ``data`` axis
         on its leading (batch) dim; on one device it goes to the default
         device."""
         mesh = self.mesh
         if mesh is None or mesh.devices.size == 1:
-            return {k: jnp.asarray(v) for k, v in batch.items()}
-        sharding = NamedSharding(mesh, P("data"))
-        return {k: jax.device_put(v, sharding) for k, v in batch.items()}
+            put = jnp.asarray
+        else:
+            sharding = NamedSharding(mesh, P("data"))
+
+            def put(v):
+                return jax.device_put(v, sharding)
+        return {k: v.map(put) if isinstance(v, PIPE.Chunks) else put(v)
+                for k, v in batch.items()}
+
+    def compile_chunks(self, loader: PIPE.Loader, state, opt_cfg,
+                       y: np.ndarray) -> None:
+        """Compile (or load from the persistent cache) every chunk
+        program the loader can ask for, by running each once on zeros:
+        a ``chunk_grad`` per chunk shape and ``apply_grads``. The
+        results are dropped."""
+        shapes = loader.chunk_shapes()
+        if not shapes:
+            return
+        yy = self.place_batch({"y": np.zeros(
+            (loader.local_batch,) + y.shape[1:], y.dtype)})["y"]
+        for rows, width in shapes:
+            ids = self.place_batch(
+                {"ids": np.zeros((rows, width), np.int32)})["ids"]
+            chunk = chunk_grad(self.apply_fn, self.heads, state[0], ids,
+                               yy, 0)
+        apply_grads(opt_cfg, state, (chunk,) * PIPE.CHUNKS)
 
     # ------------------------------------------------------------------ fit
     def fit(self, train: DS.CostDataset, *,
@@ -227,7 +317,7 @@ class TrainEngine:
                                     weight_decay=e.weight_decay)
         err0 = compress.init_error_state(params) if e.compress_grads \
             else None
-        train_step = make_train_step(self.apply_fn, opt_cfg, self.heads)
+        run_step = make_train_step(self.apply_fn, opt_cfg, self.heads)
 
         sup = fault.TrainSupervisor(e.ckpt_dir, save_every=e.save_every,
                                     keep=e.keep)
@@ -252,11 +342,16 @@ class TrainEngine:
             span = OFF
             if profiling():
                 ids = batch["ids"]
+                parts = ids.parts if isinstance(ids, PIPE.Chunks) \
+                    else (ids,)
                 span = annotate_step(   # tokens: non-PAD (id 0) positions
                     "train.step", step, rows=ids.shape[0],
-                    width=ids.shape[1], tokens=int(np.count_nonzero(ids)))
+                    width=ids.shape[1],
+                    tokens=sum(int(np.count_nonzero(p)) for p in parts),
+                    positions=sum(p.size for p in parts),
+                    chunks=len(parts))
             with span:
-                state, loss = train_step(state, placed["ids"], placed["y"])
+                state, loss = run_step(state, placed["ids"], placed["y"])
             last[0] = loss     # device value; sync only at log points
             return state
 
@@ -272,6 +367,8 @@ class TrainEngine:
         heads_extra = list(self.heads) if self.heads else [self.target]
         t0 = time.perf_counter()
         with mesh:
+            if start < e.steps:
+                self.compile_chunks(loader, state, opt_cfg, y)
             state = sup.run(
                 state, step_fn, e.steps, start_step=start,
                 extra_fn=lambda: {"loader": loader.state.as_dict(),

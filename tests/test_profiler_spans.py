@@ -227,3 +227,37 @@ def test_serving_forward_is_named(corpus, vocab_size):
     ids = jax.ShapeDtypeStruct((8, svc.buckets[0]), np.int32)
     text = svc._jit.lower(*svc._jit_args, ids).as_text()
     assert "jit_serve_forward" in text
+
+
+def test_train_step_span_layout(tmp_path):
+    """``train.step`` carries the step's layout: ``chunks`` (1 for a
+    whole batch) and ``positions``, the rows times width of each chunk
+    summed, as the loader handed the batches out."""
+    ds = DS.build_dataset(120, mode="ops", max_seq=128, vocab_size=512,
+                          augment_factor=2, seed=1)
+    eng = TR.TrainEngine("conv1d", CFG, CM.DEFAULT_HEADS[0], steps=4,
+                         batch_size=16, log_every=10)
+    y, _ = DS.normalize_targets(ds.targets[CM.DEFAULT_HEADS[0]])
+    it = iter(eng.make_loader(ds, y.astype(np.float32)))
+    want = []
+    for _ in range(4):
+        ids = next(it)["ids"]
+        parts = getattr(ids, "parts", (ids,))
+        want.append((len(parts), sum(p.shape[0] * p.shape[1]
+                                     for p in parts), ids.shape))
+    assert {c for c, _, _ in want} == {1, 4}
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.fit(ds)
+    finally:
+        jax.profiler.stop_trace()
+    steps = sorted(_named(_events(tmp_path), "train.step"),
+                   key=lambda e: e["args"]["step_num"])
+    assert len(steps) == 4
+    for e, (chunks, positions, shape) in zip(steps, want):
+        a = e["args"]
+        assert (a["chunks"], a["positions"]) == (chunks, positions)
+        assert (a["rows"], a["width"]) == shape
+        assert 0 < a["tokens"] <= a["positions"] <= a["rows"] * a["width"]

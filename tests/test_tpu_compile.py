@@ -140,3 +140,29 @@ def test_train_step_compiles_for_v5e(one_chip):
     for name in [*scopes, "pool", "heads"]:     # jvp(conv0)/..., etc.
         assert any(re.match(rf"jit\(train_step\)/.*\b{name}\b", o)
                    for o in ops), name
+
+
+def test_chunk_programs_compile_for_v5e(one_chip):
+    """A batch of 64 split into four width-sorted chunks: the gradient
+    program of one (16, 128) chunk and the program that sums the four
+    chunks' gradients and applies them, for COSTMODEL_BASE conv1d with
+    three joint heads."""
+    _, apply_fn, _ = CM.get_model("conv1d")
+    params = _params("conv1d")
+    heads = CM.DEFAULT_HEADS
+    p = _abstract(params, one_chip)
+    y = jax.ShapeDtypeStruct((64, len(heads)), np.float32,
+                             sharding=one_chip)
+    grad = TR.chunk_grad.lower(apply_fn, heads, p, _ids(one_chip, 16, 128),
+                               y, 16)
+    assert re.search(r'op_name="jit\(chunk_grad\)/.*\bconv0\b',
+                     grad.compile().as_text())
+    chunk = _abstract(jax.eval_shape(
+        lambda: TR.chunk_grad(apply_fn, heads, params,
+                              jnp.zeros((16, 128), jnp.int32),
+                              jnp.zeros((64, len(heads))), 16)), one_chip)
+    opt_cfg = adamw.AdamWConfig(lr=1e-3, total_steps=40, warmup_steps=4)
+    state = _abstract(
+        (params, jax.eval_shape(adamw.init_state, params), None), one_chip)
+    update = TR.apply_grads.lower(opt_cfg, state, (chunk,) * 4).compile()
+    assert update.memory_analysis() is not None
