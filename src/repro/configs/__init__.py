@@ -13,13 +13,14 @@ from repro.configs.qwen3_17b import CONFIG as qwen3_17b
 from repro.configs.whisper_small import CONFIG as whisper_small
 from repro.configs.llava_next_34b import CONFIG as llava_next_34b
 from repro.configs.jamba_v01_52b import CONFIG as jamba_v01_52b
+from repro.configs.jamba2_mini import CONFIG as jamba2_mini
 from repro.configs.costmodel import (COSTMODEL_SMALL, COSTMODEL_BASE,
                                      COSTMODEL_100M)
 
 ARCHS = {c.name: c for c in [
     xlstm_125m, granite_moe_1b_a400m, phi35_moe_42b_a66b, qwen15_32b,
     qwen3_06b, starcoder2_3b, qwen3_17b, whisper_small, llava_next_34b,
-    jamba_v01_52b,
+    jamba_v01_52b, jamba2_mini,
 ]}
 
 

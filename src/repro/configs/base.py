@@ -17,8 +17,14 @@ class MoEConfig:
     top_k: int
     d_ff_expert: int          # per-expert hidden dim
     moe_every: int = 1        # 1 = every layer is MoE, 2 = alternate dense/MoE
-    capacity_factor: float = 1.25
+    moe_offset: int = 0       # layer i is MoE when i % moe_every == moe_offset
+    # None = dropless: every expert has a slot for every token of a chunk
+    capacity_factor: Optional[float] = 1.25
     router_aux_weight: float = 0.01
+    renormalize: bool = True  # rescale the top-k weights to sum to one
+
+    def is_moe(self, layer: int) -> bool:
+        return layer % self.moe_every == self.moe_offset
 
 
 @dataclass(frozen=True)
@@ -29,6 +35,11 @@ class HybridConfig:
     d_state: int = 16         # mamba SSM state dim
     d_conv: int = 4           # mamba conv kernel
     expand: int = 2           # mamba inner expansion
+    dt_rank: int = 0          # 0 -> d_model // 16
+    ssm_norm: bool = False    # RMSNorm on dt, B and C (Jamba's mixer)
+
+    def resolved_dt_rank(self, d_model: int) -> int:
+        return self.dt_rank or max(d_model // 16, 1)
 
 
 @dataclass(frozen=True)
@@ -55,6 +66,7 @@ class ArchConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    rope: bool = True         # False: attention has no positional encoding
     # MoE / hybrid / xlstm sub-configs
     moe: Optional[MoEConfig] = None
     hybrid: Optional[HybridConfig] = None
@@ -110,15 +122,8 @@ class ArchConfig:
             if is_attn:
                 total += attn
             elif self.hybrid is not None:
-                # mamba block params
-                d_in = self.hybrid.expand * d
-                total += (d * 2 * d_in                 # in_proj
-                          + d_in * self.hybrid.d_conv  # conv
-                          + d_in * (self.hybrid.d_state * 2 + 1)  # x_proj-ish
-                          + d_in                        # dt
-                          + d_in * self.hybrid.d_state  # A
-                          + d_in * d)                   # out_proj
-            if self.moe is not None and (layer % self.moe.moe_every) == 0:
+                total += self._mamba_params()
+            if self.moe is not None and self.moe.is_moe(layer):
                 total += (self.moe.n_experts * 3 * d * self.moe.d_ff_expert
                           + d * self.moe.n_experts)
             elif self.d_ff > 0:
@@ -136,13 +141,28 @@ class ArchConfig:
             total += enc + cross
         return int(total)
 
+    def _mamba_params(self) -> int:
+        """One Mamba mixer, as ``models/mamba.py`` initializes it."""
+        hb, d = self.hybrid, self.d_model
+        d_in, n = hb.expand * d, hb.d_state
+        r = hb.resolved_dt_rank(d)
+        total = (d * 2 * d_in                  # in_proj
+                 + d_in * hb.d_conv + d_in     # depthwise conv, bias
+                 + d_in * (r + 2 * n)          # x_proj
+                 + r * d_in + d_in             # dt_proj, its bias
+                 + d_in * n + d_in             # A_log, D
+                 + d_in * d)                   # out_proj
+        if hb.ssm_norm:
+            total += r + 2 * n
+        return total
+
     def active_param_count(self) -> int:
         """Active params per token (MoE uses top_k of n_experts)."""
         if self.moe is None:
             return self.param_count()
         full = self.param_count()
         moe_layers = sum(1 for i in range(self.n_layers)
-                         if (i % self.moe.moe_every) == 0)
+                         if self.moe.is_moe(i))
         expert_p = 3 * self.d_model * self.moe.d_ff_expert
         inactive = (moe_layers * expert_p
                     * (self.moe.n_experts - self.moe.top_k))
@@ -171,7 +191,8 @@ class ArchConfig:
                 d_ff_expert=32)
         if self.hybrid is not None:
             kw["hybrid"] = dataclasses.replace(
-                self.hybrid, period=4, attn_index=2, d_state=8, expand=2)
+                self.hybrid, period=4, attn_index=2, d_state=8, expand=2,
+                dt_rank=0)
             kw["n_layers"] = 4
         if self.xlstm is not None:
             kw["xlstm"] = dataclasses.replace(self.xlstm, slstm_at=(1,))

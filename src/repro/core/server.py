@@ -321,7 +321,8 @@ class CostModelServer:
                 if hit is not None:
                     ids = None
             if sp is not None:
-                sp.set_metadata(hit=int(hit is not None))
+                sp.set_metadata(hit=int(hit is not None), truncated=int(
+                    ids is not None and self.service.truncated(key)))
         return self._submit_resolved(key, ids, hit, trace=trace)
 
     def submit_entry(self, key: str, ids: np.ndarray, *,

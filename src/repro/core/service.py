@@ -58,7 +58,7 @@ import numpy as np
 
 from repro.core import models as CM
 from repro.core import tokenizer as TOK
-from repro.ir.graph import Graph
+from repro.ir.graph import WHILE_OP, Graph
 
 
 # Canonical bucket ladder lives in the dataset layer (training and serving
@@ -320,8 +320,9 @@ class CostModelService:
         cached ids: copied op spans are gathered with one vectorized
         index, only the rewrite's dirty ops (plus the small output tail)
         are re-lexed. Returns None when no parent ids are cached, the
-        mode is not "ops", or either side truncates (fresh encode then
-        handles — and counts — the truncation)."""
+        mode is not "ops", the graph holds a loop (three tokens, not
+        two), or either side truncates (fresh encode then handles — and
+        counts — the truncation)."""
         delta = g._tok_delta
         if delta is None or self.mode != "ops":
             return None
@@ -350,6 +351,8 @@ class CostModelService:
         t2i = self.vocab.token_to_id
         unk = t2i[TOK.UNK]
         for j, op in enumerate(g.ops):               # dirty ops only
+            if op.opcode == WHILE_OP:    # a loop adds its trips token
+                return None
             if j in op_map:
                 continue
             out[base + 2 * j] = t2i.get(f"xpu.{op.opcode}", unk)
@@ -363,6 +366,13 @@ class CostModelService:
         with self._cache_lock:
             self.delta_encodes += 1
         return out, n_tok
+
+    def truncated(self, key: str) -> bool:
+        """Whether the ids cached for ``key`` were cut at their width
+        (False where none are cached)."""
+        with self._cache_lock:
+            ent = self._ids_cache.get(key)
+        return ent is not None and ent[1] > len(ent[0])
 
     def _ids_cache_get(self, key: str) -> Optional[np.ndarray]:
         with self._cache_lock:

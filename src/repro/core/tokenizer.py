@@ -6,6 +6,12 @@
 * ``ops_operands``  — opcodes AND SSA operand names (``%3``, ``%arg1``) and
                       per-op output shape, in source order (~4x longer).
 
+In both, a loop (``xpu.while``) is followed by its trip count as one
+token, ``<trips=N>``, or ``<trips=?>`` where the count is not static
+(:func:`trips_token`); ``vocab_from_counts`` and ``fit_vocab`` add the
+unknown count and every power of two up to 2**20 (:data:`LOOP_TOKENS`)
+after the counted tokens, where ``max_size`` leaves room.
+
 Unseen shape tokens or ``%k`` names become ``<unk>`` (the paper's OOV
 failure mode, reproduced faithfully) — unless the vocab was built or
 extended with the OOV machinery below, in which case they degrade
@@ -44,7 +50,7 @@ from typing import Dict, Iterable, List, Sequence
 
 import numpy as np
 
-from repro.ir.graph import Graph
+from repro.ir.graph import WHILE_OP, Graph
 
 PAD, UNK, BOS, EOS, SEP = "<pad>", "<unk>", "<bos>", "<eos>", "<sep>"
 SPECIALS = [PAD, UNK, BOS, EOS, SEP]
@@ -53,6 +59,14 @@ SPECIALS = [PAD, UNK, BOS, EOS, SEP]
 # longer ones (huge attribute blobs) go to an unk shard instead so one
 # pathological token can't flood the sequence budget.
 BYTE_FALLBACK_MAX = 16
+
+
+def trips_token(trips) -> str:
+    """The token that carries a loop's trip count (None: unknown)."""
+    return "<trips=?>" if trips is None else f"<trips={int(trips)}>"
+
+
+LOOP_TOKENS = [trips_token(None)] + [trips_token(1 << k) for k in range(21)]
 
 
 def unk_shard_token(k: int) -> str:
@@ -91,11 +105,15 @@ def graph_tokens(g: Graph, mode: str = "ops") -> List[str]:
         # (and hence data dependence) dropped — paper's first scheme
         for op in g.ops:
             toks.append(f"xpu.{op.opcode}")
+            if op.opcode == WHILE_OP:
+                toks.append(trips_token(op.attrs.get("trips")))
             toks.append(g.values[op.result].shape_token())
     elif mode == "ops_operands":
         for op in g.ops:
             toks.append(g.ssa_name(op.result))
             toks.append(f"xpu.{op.opcode}")
+            if op.opcode == WHILE_OP:
+                toks.append(trips_token(op.attrs.get("trips")))
             toks.extend(g.ssa_name(o) for o in op.operands)
             toks.append(g.values[op.result].shape_token())
     else:
@@ -299,7 +317,10 @@ def vocab_from_counts(counts: Counter, max_size: int = 8192,
     """Build a Vocab from pre-accumulated token counts (the streaming
     count-then-encode path: pass 1 counts, pass 2 encodes). With
     ``n_unk_buckets`` / ``byte_fallback``, the OOV machinery tokens are
-    reserved FIRST so they always fit under ``max_size``."""
+    reserved FIRST so they always fit under ``max_size``. The loop
+    tokens (:data:`LOOP_TOKENS`) follow the counted ones, as far as
+    ``max_size`` leaves room, so the counted tokens keep the ids they
+    had before the loop tokens existed."""
     vocab = {t: i for i, t in enumerate(SPECIALS)}
     for k in range(n_unk_buckets):
         vocab[unk_shard_token(k)] = len(vocab)
@@ -311,6 +332,10 @@ def vocab_from_counts(counts: Counter, max_size: int = 8192,
             break
         if c >= min_count and tok not in vocab:
             vocab[tok] = len(vocab)
+    for tok in LOOP_TOKENS:
+        if len(vocab) >= max_size:
+            break
+        vocab.setdefault(tok, len(vocab))
     return Vocab(vocab, n_unk_buckets=n_unk_buckets,
                  byte_fallback=byte_fallback)
 

@@ -39,7 +39,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import tokenizer as TOK
-from repro.ir.graph import Graph, Tensor
+from repro.ir.graph import WHILE_OP, Graph, Tensor
+from repro.obs.trace import annotate
 
 
 # ------------------------------------------------------------ result types
@@ -118,20 +119,39 @@ def prediction_from(entry: TextEntry,
 
 # ------------------------------------------------------------- the parser
 # `%out = "dialect.op"(...)` (generic) or `%out = dialect.op ...`
-# (pretty). Multi-result ops (`%0:2 = ...`) keep one result value.
+# (pretty); results may be a list (`%values, %indices = ...`) or carry
+# a count (`%0:2 = ...`, used as `%0#1`).
+_RESULTS = r"%[\w]+(?::\d+)?(?:\s*,\s*%[\w]+(?::\d+)?)*"
 _OP_RE = re.compile(
-    r'^\s*%([A-Za-z0-9_]+)(?::\d+)?\s*=\s*'
+    r'^\s*(' + _RESULTS + r')\s*=\s*'
     r'(?:"([A-Za-z_][\w$.]*)"|([A-Za-z_]\w*\.[\w.]+))\s*(.*)$')
+# `%r = call @f(%a, %b) : ...` / `%r:2 = func.call @f(...)`
+_CALL_RE = re.compile(
+    r'^\s*(?:(' + _RESULTS + r')\s*=\s*)?(?:func\.)?call\s+@([\w$.\-]+)'
+    r'\s*\(([^)]*)\)')
+_RESULT_NAME_RE = re.compile(r"%([\w]+)")
+_DEFINES_RE = re.compile(r"^\s*" + _RESULTS + r"\s*=")
 _TYPE_RE = re.compile(r"(?:tensor|memref|vector)<([^>]*)>")
-_OPERAND_RE = re.compile(r"%([A-Za-z0-9_]+)")
+_OPERAND_RE = re.compile(r"%([A-Za-z0-9_]+)(?:#(\d+))?")
 _RETURN_RE = re.compile(r"^\s*(?:func\.)?return\b(.*)$")
+_FUNC_RE = re.compile(r"^\s*func\.func\s+(?:(?:public|private|nested)\s+)?"
+                      r"@([\w$.\-]+)")
+_BLOCK_ARGS_RE = re.compile(r"^\s*\^bb\d+\s*\((.*)\)\s*:")
+_ITER_ARG_RE = re.compile(r"%([\w]+)\s*=\s*%([\w]+)(?:#\d+)?")
+_CONST_INT_RE = re.compile(
+    r"^\s*%([\w]+)\s*=\s*stablehlo\.constant\s+dense<(-?\d+)>\s*:"
+    r"\s*tensor<[su]?i\d+>")
+_COMPARE_RE = re.compile(
+    r"stablehlo\.compare\s+(LT|LE|GT|GE),\s*%([\w]+),\s*%([\w]+)")
+_APPLIES_RE = re.compile(r"\bapplies\s+(?:\w+\.)?(\w+)")
 _SCALAR_DTYPES = ("bf16", "f64", "f32", "f16",
                   "i64", "i32", "i16", "i8", "i1")
+MAX_INLINE_DEPTH = 32
 
-# Known op-name -> xpu opcode translations (StableHLO / arith / math /
-# the repo's own printer). Unknown names keep their bare op name, which
-# the OOV-extended tokenizer resolves to shard/byte ids instead of a
-# single <unk>.
+# Op-name -> xpu opcode translations (StableHLO / arith / math / the
+# repo's own printer) for ops the xpu dialect names otherwise; every
+# other op keeps its own name, which the tokenizer resolves (OOV
+# machinery) instead of collapsing it onto a different op.
 OPCODE_MAP = {
     "dot_general": "matmul", "dot": "matmul", "einsum": "matmul",
     "convolution": "conv2d", "conv": "conv2d",
@@ -143,15 +163,15 @@ OPCODE_MAP = {
     "minimum": "minimum", "minf": "minimum",
     "exponential": "exp", "exp": "exp", "negate": "neg", "abs": "abs",
     "tanh": "tanh", "logistic": "sigmoid", "rsqrt": "rsqrt",
-    "sqrt": "rsqrt", "power": "exp",
-    "reduce": "reduce_sum", "reduce_sum": "reduce_sum",
-    "reduce_max": "reduce_max", "reduce_window": "pool_max",
+    "reduce_sum": "reduce_sum", "reduce_max": "reduce_max",
     "broadcast_in_dim": "broadcast", "broadcast": "broadcast",
     "reshape": "reshape", "transpose": "transpose",
-    "concatenate": "concat", "slice": "slice",
-    "dynamic_slice": "slice", "pad": "pad", "select": "maximum",
+    "concatenate": "concat", "slice": "slice", "pad": "pad",
     "load": "slice", "store": "pad",      # affine/memref data movement
 }
+# ``stablehlo.reduce ... applies <op>``: the reduction's own kind
+REDUCE_MAP = {"add": "reduce_sum", "maximum": "reduce_max",
+              "minimum": "reduce_min", "multiply": "reduce_prod"}
 
 
 def _parse_type(txt: str) -> Tensor:
@@ -177,11 +197,21 @@ def _parse_type(txt: str) -> Tensor:
     return Tensor((), "f32")
 
 
-def _xpu_opcode(raw: str) -> str:
+def _xpu_opcode(raw: str, rest: str = "") -> str:
     """Map ``dialect.op`` onto an xpu opcode; unknown names keep the
-    sanitized op name (OOV-safe downstream)."""
+    sanitized op name (OOV-safe downstream). A pretty-printed reduce
+    names its reduction (``applies stablehlo.add`` -> ``reduce_sum``)."""
     name = raw.rsplit(".", 1)[-1]
+    if name == "reduce":
+        m = _APPLIES_RE.search(rest)
+        if m:
+            return REDUCE_MAP.get(m.group(1), f"reduce_{m.group(1)}")
     return OPCODE_MAP.get(name, name)
+
+
+def _head(lines: Sequence[str]) -> str:
+    """A function's signature: its lines up to the body's brace."""
+    return "\n".join(lines[:8]).split("{")[0] + "{"
 
 
 def _signature_args(text: str) -> List[Tuple[str, Tensor]]:
@@ -197,51 +227,263 @@ def _signature_args(text: str) -> List[Tuple[str, Tensor]]:
     return args
 
 
+def _functions(text: str) -> Tuple[Dict[str, List[str]], Optional[str]]:
+    """Split a module into its functions' lines (signature first) and
+    name the entry: ``@main``, else the first public function, else the
+    first. Text with no ``func.func`` is one anonymous function."""
+    funcs: Dict[str, List[str]] = {}
+    order: List[Tuple[str, bool]] = []
+    cur: Optional[List[str]] = None
+    for line in text.splitlines():
+        m = _FUNC_RE.match(line)
+        if m:
+            cur = funcs.setdefault(m.group(1), [])
+            order.append((m.group(1), "private" not in line.split("@")[0]))
+        elif cur is None:
+            cur = funcs.setdefault("", [])
+            order.append(("", True))
+        cur.append(line)
+    if not order:
+        return funcs, None
+    names = [n for n, _ in order]
+    if "main" in funcs:
+        return funcs, "main"
+    public = [n for n, pub in order if pub and n]
+    return funcs, (public[0] if public else names[0])
+
+
+def _region_end(lines: Sequence[str], i: int) -> int:
+    """Index past the last line of the regions that the op on line
+    ``i`` opens (by brace depth; attribute dicts balance on a line)."""
+    depth = lines[i].count("{") - lines[i].count("}")
+    j = i + 1
+    opened = depth > 0
+    while j < len(lines):
+        if depth <= 0 and opened:
+            break
+        depth += lines[j].count("{") - lines[j].count("}")
+        opened = opened or depth > 0
+        j += 1
+    return j
+
+
+def _int_constants(lines: Sequence[str]) -> Dict[str, int]:
+    out: Dict[str, int] = {}
+    for line in lines:
+        m = _CONST_INT_RE.match(line)
+        if m:
+            out[m.group(1)] = int(m.group(2))
+    return out
+
+
+def while_trips(lines: Sequence[str], i: int,
+                outer: Dict[str, int]) -> Optional[int]:
+    """Static trip count of the ``stablehlo.while`` on line ``i``, or
+    None. The loops ``lax.scan``/``fori_loop``/``map`` emit count one
+    iteration argument from a constant start to a constant bound
+    (``compare LT``/``LE`` in ``cond``) by a constant step (an ``add``
+    of it in ``do``). ``outer`` holds the integer constants defined
+    before the loop in its function."""
+    head = lines[i]
+    inits = dict(_ITER_ARG_RE.findall(head[head.find("("):]))
+    end = _region_end(lines, i)
+    body = lines[i + 1:end]
+    split = next((k for k, ln in enumerate(body)
+                  if re.match(r"^\s*\}\s*do\s*\{", ln)), None)
+    if split is None:
+        return None
+    cond, do = body[:split], body[split:]
+    cond_c = dict(outer, **_int_constants(cond))
+    do_c = dict(outer, **_int_constants(do))
+    for ln in cond:
+        m = _COMPARE_RE.search(ln)
+        if m is None:
+            continue
+        kind, a, b = m.groups()
+        if a in inits and b in cond_c:
+            arg, bound = a, cond_c[b]
+        elif b in inits and a in cond_c and kind in ("GT", "GE"):
+            arg, bound = b, cond_c[a]
+            kind = "LT" if kind == "GT" else "LE"
+        else:
+            return None
+        if kind not in ("LT", "LE") or inits[arg] not in outer:
+            return None
+        start = outer[inits[arg]]
+        step = None
+        for dl in do:
+            am = re.search(r"stablehlo\.add\s+%([\w]+),\s*%([\w]+)", dl)
+            if am is None or arg not in am.groups():
+                continue
+            other = am.group(2) if am.group(1) == arg else am.group(1)
+            if other in do_c:
+                step = do_c[other]
+                break
+        if not step or step <= 0:
+            return None
+        span = bound - start + (1 if kind == "LE" else 0)
+        return max(0, -(-span // step))
+    return None
+
+
+@dataclass
+class ParseStats:
+    """What one parse recovered and what it could not: ``whiles`` and
+    those of unknown trip count, and ``lossy`` op lines dropped or
+    calls left un-inlined."""
+    ops: int = 0
+    whiles: int = 0
+    unknown_trips: int = 0
+    lossy: int = 0
+
+
+def _lookup(env: Dict[str, int], name: str, idx: Optional[str]
+            ) -> Optional[int]:
+    if idx is not None and f"{name}#{idx}" in env:
+        return env[f"{name}#{idx}"]
+    return env.get(name)
+
+
+def _operands(env: Dict[str, int], txt: str) -> List[int]:
+    out = []
+    for name, idx in _OPERAND_RE.findall(txt):
+        v = _lookup(env, name, idx or None)
+        if v is not None:
+            out.append(v)
+    return out
+
+
+def _bind(env: Dict[str, int], results: str, vids: Sequence[int]) -> None:
+    """Bind an op's result names: ``%a, %b`` one each; ``%0:2`` as
+    ``%0`` and ``%0#k``."""
+    names = _RESULT_NAME_RE.findall(results)
+    if len(names) > 1:
+        for k, n in enumerate(names):
+            env[n] = vids[min(k, len(vids) - 1)]
+        return
+    env[names[0]] = vids[0]
+    for k, v in enumerate(vids):
+        env[f"{names[0]}#{k}"] = v
+
+
+def _parse_function(g: Graph, funcs: Dict[str, List[str]], name: str,
+                    env: Dict[str, int], stats: ParseStats,
+                    stack: Tuple[str, ...]) -> List[int]:
+    """Append one function's ops to ``g`` (``env`` binds its arguments)
+    and return the values it returns; calls are inlined."""
+    lines = funcs[name]
+    returns: List[int] = []
+    consts: Dict[str, int] = {}
+    for i, line in enumerate(lines):
+        if _FUNC_RE.match(line):
+            continue
+        bm = _BLOCK_ARGS_RE.match(line)
+        if bm:                       # a region's block arguments
+            if g.ops:
+                for n in _RESULT_NAME_RE.findall(bm.group(1)):
+                    env[n] = g.ops[-1].result
+            continue
+        rm = _RETURN_RE.match(line)
+        if rm:
+            returns = _operands(env, rm.group(1).split(" : ")[0])
+            continue
+        cm = _CALL_RE.match(line)
+        if cm:
+            results, callee, args = cm.groups()
+            operands = _operands(env, args)
+            if (callee in funcs and callee not in stack
+                    and len(stack) < MAX_INLINE_DEPTH):
+                sub = {n: v for (n, _), v in zip(
+                    _signature_args(_head(funcs[callee])), operands)}
+                outs = _parse_function(g, funcs, callee, sub, stats,
+                                       stack + (callee,))
+            else:
+                stats.lossy += 1
+                arrow = line.rsplit("->", 1)
+                outs = [g.add_op("call", operands, _parse_type(
+                    arrow[1] if len(arrow) == 2 else line))]
+            if results and outs:
+                _bind(env, results, outs)
+            elif results:
+                stats.lossy += 1
+            continue
+        m = _OP_RE.match(line)
+        if m is None:
+            if _DEFINES_RE.match(line):
+                stats.lossy += 1      # a defining line we cannot read
+            continue
+        results, raw_op = m.group(1), m.group(2) or m.group(3)
+        rest = m.group(4)
+        cm_int = _CONST_INT_RE.match(line)
+        if cm_int:
+            consts[cm_int.group(1)] = int(cm_int.group(2))
+        opcode = _xpu_opcode(raw_op, rest)
+        attrs = {}
+        if opcode == WHILE_OP:
+            iters = _ITER_ARG_RE.findall(rest[rest.find("("):])
+            operands = [env[init] for _, init in iters if init in env]
+            trips = while_trips(lines, i, consts)
+            attrs["trips"] = trips
+            stats.whiles += 1
+            stats.unknown_trips += trips is None
+        else:
+            # operands: %refs before the trailing type annotation
+            operands = _operands(env, rest.split(" : ")[0])
+        # result type: prefer the type after ->, else the last type in
+        # the line, else scalar f32
+        arrow = rest.rsplit("->", 1)
+        t = _parse_type(arrow[1] if len(arrow) == 2 else rest)
+        vid = g.add_op(opcode, operands, t, **attrs)
+        _bind(env, results, [vid])
+        if opcode == WHILE_OP:          # the body reads the loop's state
+            for it, _ in iters:
+                env[it] = vid
+    return returns
+
+
+def parse_mlir_stats(text: str) -> Tuple[Optional[Graph], ParseStats]:
+    """:func:`parse_mlir` and what it recovered (:class:`ParseStats`)."""
+    stats = ParseStats()
+    try:
+        funcs, entry = _functions(text)
+        if entry is None:
+            return None, stats
+        g = Graph(name="ingested")
+        env: Dict[str, int] = {}
+        for name, t in _signature_args(_head(funcs[entry])):
+            if name not in env:
+                env[name] = g.add_arg(t)
+        returns = _parse_function(g, funcs, entry, env, stats, (entry,))
+        if not g.ops:
+            return None, stats
+        stats.ops = len(g.ops)
+        g.outputs = returns or [g.ops[-1].result]
+        g.validate()
+        return g, stats
+    except Exception:
+        return None, stats
+
+
 def parse_mlir(text: str) -> Optional[Graph]:
     """Best-effort structural parse of MLIR text into a Graph.
 
     Returns None when no SSA ops are recoverable (callers fall back to
-    the token stream). Never raises: unparsable lines are skipped,
-    unknown operand references are dropped from the op's operand list,
-    and region ops (reduce bodies etc.) flatten into the op sequence.
+    the token stream). Never raises: unparsable lines are skipped and
+    unknown operand references are dropped from the op's operand list.
+    The entry function's ops come in program order with every
+    ``func.call`` replaced by its callee's ops; region ops (loop
+    bodies, reduction bodies) follow the op that owns them. A
+    ``stablehlo.while`` becomes a ``while`` op whose ``trips``
+    attribute holds its static trip count (None where the bound is not
+    a constant); its iteration arguments read the loop's state, the
+    ``while`` op's result.
     """
-    try:
-        g = Graph(name="ingested")
-        env: Dict[str, int] = {}
-        for name, t in _signature_args(text):
-            if name not in env:
-                env[name] = g.add_arg(t)
-        returns: List[str] = []
-        for line in text.splitlines():
-            rm = _RETURN_RE.match(line)
-            if rm:
-                returns.extend(_OPERAND_RE.findall(rm.group(1)))
-                continue
-            m = _OP_RE.match(line)
-            if m is None:
-                continue
-            out_name = m.group(1)
-            raw_op = m.group(2) or m.group(3)
-            rest = m.group(4)
-            # operands: %refs before the trailing type annotation
-            head = rest.split(" : ")[0]
-            operands = [env[r] for r in _OPERAND_RE.findall(head)
-                        if r in env]
-            # result type: prefer the type after ->, else the last
-            # type in the line, else scalar f32
-            arrow = rest.rsplit("->", 1)
-            t = _parse_type(arrow[1] if len(arrow) == 2 else rest)
-            if out_name in env:          # redefinition (regions): skip
-                continue
-            env[out_name] = g.add_op(_xpu_opcode(raw_op), operands, t)
-        if not g.ops:
-            return None
-        outs = [env[r] for r in returns if r in env]
-        g.outputs = outs or [g.ops[-1].result]
-        g.validate()
-        return g
-    except Exception:
-        return None
+    with annotate("frontdoor.parse") as sp:
+        g, st = parse_mlir_stats(text)
+        if sp is not None:
+            sp.set_metadata(ops=st.ops, whiles=st.whiles,
+                            unknown_trips=st.unknown_trips, lossy=st.lossy)
+    return g
 
 
 def _dialects(text: str) -> Tuple[str, ...]:

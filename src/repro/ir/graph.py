@@ -244,6 +244,10 @@ class Graph:
 # of these sets so category membership stays paper-faithful — the analyzers
 # model it explicitly.
 FUSED_OP = "fused"
+# A loop ingested from MLIR (``stablehlo.while``): its ``trips`` attr is
+# the static trip count, None where the front door could not read one;
+# the ops of its regions follow it.
+WHILE_OP = "while"
 ELEMENTWISE = {"add", "sub", "mult", "div", "relu", "gelu", "silu", "tanh",
                "sigmoid", "exp", "neg", "abs", "maximum", "minimum", "rsqrt"}
 REDUCTION = {"softmax", "layernorm", "batchnorm", "reduce_sum", "reduce_max",

@@ -10,12 +10,18 @@ Graph sources:
 
 * :func:`sample_stablehlo_corpus` — a fixed pool of jnp subgraphs
   (mlp / attention / conv / norm-residual) mirroring the xpu op mix.
+* :func:`layer_programs` — the real layer programs of a hybrid
+  architecture, whose blocks ``repro.models`` implements in full
+  (jamba2-mini, jamba-v0.1-52b): its attention, Mamba, MoE and dense
+  FFN layers at the published widths, for training (forward and
+  backward), prefill and one decode step against a cache, lowered from
+  ``jax.ShapeDtypeStruct`` specs (no tensor data materialized).
 * :func:`arch_subgraphs` / :func:`lower_arch_corpus` — per-layer
-  subgraphs (attention, SwiGLU MLP, norms, router, lm head) of the real
-  architectures registered in ``repro.configs.ARCHS`` at reduced
-  widths, lowered from ``jax.ShapeDtypeStruct`` specs (no tensor data
-  materialized). These are the "ingest a program we did not generate"
-  acceptance inputs for the front door.
+  subgraphs (attention, SwiGLU MLP, norms, router, lm head) of the other
+  architectures registered in ``repro.configs.ARCHS``: hand-written
+  stand-ins at reduced widths. These, and the real layer programs, are
+  the "ingest a program we did not generate" acceptance inputs for the
+  front door.
 
 The affine/scf "lower-level dialects produce much larger sequences"
 scenario is NOT produced here — nothing in this module lowers to
@@ -177,14 +183,119 @@ def arch_subgraphs(name: str, batch: int = 1, seq: int = 8
     return out
 
 
+# --------------------------------------------- real layer programs
+LAYER_KINDS = ("attention", "mamba", "moe", "ffn")
+PROGRAM_KINDS = ("train", "prefill", "decode")
+
+
+def _layer_specs(cfg, layer: str):
+    """Abstract parameters of one layer at the config's widths (every
+    expert of an MoE layer)."""
+    from repro.models import layers as L
+    from repro.models import mamba as MB
+    from repro.models import moe as M
+    key = jax.random.PRNGKey(0)
+    init = {"attention": lambda: L.attention_init(key, cfg),
+            "mamba": lambda: MB.mamba_init(key, cfg),
+            "moe": lambda: M.moe_init(key, cfg),
+            "ffn": lambda: L.ffn_init(key, cfg.d_model, cfg.d_ff)}[layer]
+    return jax.eval_shape(init)
+
+
+def _layer_fn(cfg, layer: str, cdt):
+    """``f(params, x) -> y`` of one layer on a whole sequence."""
+    from repro.models import layers as L
+    from repro.models import mamba as MB
+    from repro.models import moe as M
+
+    def attention(p, x):
+        pos = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
+        return L.attention_apply(p, x, cfg, positions=pos, cdt=cdt)[0]
+    return {"attention": attention,
+            "mamba": lambda p, x: MB.mamba_apply(p, x, cfg, cdt=cdt)[0],
+            "moe": lambda p, x: M.moe_apply(p, x, cfg, cdt=cdt)[0],
+            "ffn": lambda p, x: L.ffn_apply(p, x, cdt=cdt)}[layer]
+
+
+def layer_program(arch: str, layer: str, kind: str, batch: int,
+                  length: int, cdt=jnp.bfloat16) -> Tuple[Callable, Tuple]:
+    """``(fn, arg_specs)`` of one layer program of ``arch`` at its
+    published widths: ``train`` is the layer's forward and backward
+    (``jax.vjp`` against an output cotangent) over ``batch`` x
+    ``length`` positions, ``prefill`` its forward, and ``decode`` one
+    token per row against a KV cache of ``length`` positions
+    (attention) or the conv and SSM state (Mamba); the MoE and FFN
+    decode steps do not depend on ``length``."""
+    from repro.configs import get_arch
+    from repro.models import layers as L
+    from repro.models import mamba as MB
+    cfg = get_arch(arch)
+    if cfg.family != "hybrid" or layer not in LAYER_KINDS:
+        raise ValueError(f"no real {layer!r} layer program for {arch!r}")
+    p = _layer_specs(cfg, layer)
+    d = cfg.d_model
+
+    def spec(*shape, dtype=cdt):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    fwd = _layer_fn(cfg, layer, cdt)
+    if kind == "prefill":
+        return fwd, (p, spec(batch, length, d))
+    if kind == "train":
+        def train(params, x, dy):
+            y, pull = jax.vjp(fwd, params, x)
+            return y, pull(dy)
+        return train, (p, spec(batch, length, d), spec(batch, length, d))
+    if kind != "decode":
+        raise ValueError(f"unknown program kind {kind!r}")
+    x = spec(batch, 1, d)
+    if layer == "attention":
+        nkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+        cache = {"k": spec(batch, nkv, length, hd),
+                 "v": spec(batch, nkv, length, hd)}
+
+        def step(params, x, cache, index):
+            pos = jnp.broadcast_to(index, (x.shape[0], 1))
+            return L.attention_apply(params, x, cfg, positions=pos,
+                                     cdt=cdt, cache=cache,
+                                     cache_index=index)
+        return step, (p, x, cache, spec(dtype=jnp.int32))
+    if layer == "mamba":
+        hb = cfg.hybrid
+        di = hb.expand * d
+        state = {"conv": spec(batch, hb.d_conv - 1, di),
+                 "ssm": spec(batch, di, hb.d_state, dtype=jnp.float32)}
+        return (lambda params, x, st: MB.mamba_apply(
+            params, x, cfg, cdt=cdt, state=st)), (p, x, state)
+    return fwd, (p, x)
+
+
+def layer_programs(arch: str, kind: str, batch: int, length: int
+                   ) -> List[Tuple[str, str]]:
+    """``(layer, stablehlo_text)`` of each layer kind of ``arch``
+    (attention, Mamba, MoE, dense FFN) for one program kind, batch and
+    length (:func:`layer_program`)."""
+    out = []
+    for layer in LAYER_KINDS:
+        fn, specs = layer_program(arch, layer, kind, batch, length)
+        out.append((layer, jax.jit(fn).lower(*specs).as_text()))
+    return out
+
+
 def lower_arch_corpus(names: Optional[List[str]] = None, batch: int = 1,
                       seq: int = 8) -> List[Tuple[str, str, str]]:
     """Lower every per-layer subgraph of the given architectures ->
-    ``(arch, layer, stablehlo_text)`` rows. ``names=None`` lowers all
-    registered archs."""
+    ``(arch, layer, stablehlo_text)`` rows: the real layer programs
+    (prefill at ``batch`` x ``seq``) of the hybrid archs, the stand-ins
+    of the others. ``names=None`` lowers all registered
+    archs."""
     from repro.configs import ARCHS
     rows: List[Tuple[str, str, str]] = []
     for name in (names if names is not None else sorted(ARCHS)):
+        if ARCHS[name].family == "hybrid":
+            rows.extend((name, layer, text) for layer, text in
+                        layer_programs(name, "prefill", batch, seq))
+            continue
         for layer, fn, specs in arch_subgraphs(name, batch=batch,
                                                seq=seq):
             text = jax.jit(fn).lower(*specs).as_text()

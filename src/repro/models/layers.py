@@ -146,16 +146,9 @@ def flash_attention(q, k, v, *, causal: bool, q_offset=0,
     return out.astype(q.dtype)
 
 
-def attention_apply(p, x, cfg, *, positions, rules=None, cdt=jnp.bfloat16,
-                    cache: Optional[Dict] = None, cache_index=None):
-    """GQA attention. If cache is given, single-token decode; else full seq.
-
-    cache: {"k": (B, n_kv, S_cache, D), "v": same} sharded on cache_seq.
-    Returns (out, new_cache).
-    """
-    B, S, d = x.shape
-    nq, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    G = nq // nkv
+def _qkv(p, x, cfg, positions, cdt):
+    """Queries, keys and values (B, S, heads, D) of ``x``, with bias,
+    qk-norm and rotary embedding where the config has them."""
     xc = x.astype(cdt)
     q = jnp.einsum("bsd,dhk->bshk", xc, p["wq"].astype(cdt))
     k = jnp.einsum("bsd,dhk->bshk", xc, p["wk"].astype(cdt))
@@ -167,8 +160,30 @@ def attention_apply(p, x, cfg, *, positions, rules=None, cdt=jnp.bfloat16,
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def attention_kv(p, x, cfg, *, positions, cdt=jnp.bfloat16):
+    """The keys and values a prefill of ``x`` writes into a decode
+    cache, in its layout: {"k", "v": (B, n_kv, S, D)}."""
+    _, k, v = _qkv(p, x, cfg, positions, cdt)
+    return {"k": k.transpose(0, 2, 1, 3), "v": v.transpose(0, 2, 1, 3)}
+
+
+def attention_apply(p, x, cfg, *, positions, rules=None, cdt=jnp.bfloat16,
+                    cache: Optional[Dict] = None, cache_index=None):
+    """GQA attention. If cache is given, single-token decode; else full seq.
+
+    cache: {"k": (B, n_kv, S_cache, D), "v": same} sharded on cache_seq.
+    Returns (out, new_cache).
+    """
+    B, S, d = x.shape
+    nq, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    G = nq // nkv
+    q, k, v = _qkv(p, x, cfg, positions, cdt)
 
     if cache is None:
         # train/prefill: repeat kv to full q heads, flash attention.

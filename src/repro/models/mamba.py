@@ -6,6 +6,13 @@ tensor never materializes at full sequence length. Decode is the O(1)
 recurrent update. d_inner shards over the ``model`` mesh axis ("ffn" logical
 axis) — conv/gating are elementwise over d_inner, and the B/C projections
 reduce over the sharded dim (GSPMD inserts the small all-reduces).
+
+With ``cfg.hybrid.ssm_norm`` (Jamba) dt, B and C each pass through an
+RMSNorm of their own (weights ``dt_norm``, ``b_norm``, ``c_norm``, eps
+``cfg.norm_eps``) after ``x_proj``, as transformers' ``JambaMambaMixer``
+does. A prefill asked for its state (``return_state=True``) hands back
+the conv window and the SSM state after its last position, which a
+decode step continues from.
 """
 from __future__ import annotations
 
@@ -15,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.layers import _init
+from repro.models.layers import _init, rms_norm
 
 MAMBA_CHUNK = 256
 
@@ -25,8 +32,8 @@ def mamba_init(key, cfg) -> Dict[str, Any]:
     d = cfg.d_model
     di = h.expand * d
     ks = jax.random.split(key, 6)
-    dt_rank = max(d // 16, 1)
-    return {
+    dt_rank = h.resolved_dt_rank(d)
+    p = {
         "in_proj": _init(ks[0], (d, 2 * di)),
         "conv_w": _init(ks[1], (h.d_conv, di), scale=0.5),
         "conv_b": jnp.zeros((di,)),
@@ -41,10 +48,14 @@ def mamba_init(key, cfg) -> Dict[str, Any]:
         "D": jnp.ones((di,)),
         "out_proj": _init(ks[5], (di, d)),
     }
+    if h.ssm_norm:
+        p.update(dt_norm=jnp.ones((dt_rank,)), b_norm=jnp.ones((h.d_state,)),
+                 c_norm=jnp.ones((h.d_state,)))
+    return p
 
 
 def mamba_axes(cfg):
-    return {
+    a = {
         "in_proj": ("embed", "ffn"),
         "conv_w": (None, "ffn"),
         "conv_b": ("ffn",),
@@ -55,6 +66,9 @@ def mamba_axes(cfg):
         "D": ("ffn",),
         "out_proj": ("ffn", "embed"),
     }
+    if cfg.hybrid.ssm_norm:
+        a.update(dt_norm=(None,), b_norm=(None,), c_norm=(None,))
+    return a
 
 
 def _causal_conv(x, w, b, state: Optional[jnp.ndarray] = None):
@@ -76,6 +90,10 @@ def _ssm_params(p, x, cfg, cdt):
     dt_rank = p["dt_proj"].shape[0]
     proj = x @ p["x_proj"].astype(cdt)
     dt_in, Bm, Cm = jnp.split(proj, [dt_rank, dt_rank + h.d_state], axis=-1)
+    if h.ssm_norm:
+        dt_in = rms_norm(dt_in, p["dt_norm"], cfg.norm_eps)
+        Bm = rms_norm(Bm, p["b_norm"], cfg.norm_eps)
+        Cm = rms_norm(Cm, p["c_norm"], cfg.norm_eps)
     dt = jax.nn.softplus((dt_in @ p["dt_proj"].astype(cdt)).astype(jnp.float32)
                          + p["dt_bias"])
     A = -jnp.exp(p["A_log"])  # (di, N)
@@ -83,10 +101,11 @@ def _ssm_params(p, x, cfg, cdt):
 
 
 def mamba_apply(p, x, cfg, *, rules=None, cdt=jnp.bfloat16,
-                state: Optional[Dict] = None):
+                state: Optional[Dict] = None, return_state: bool = False):
     """x: (B, S, D). state (decode): {"conv": (B,k-1,di), "ssm": (B,di,N)}.
 
-    Returns (out, new_state)."""
+    Returns (out, new_state); a prefill's new_state is None unless
+    ``return_state``."""
     B, S, D = x.shape
     h = cfg.hybrid
     di = h.expand * D
@@ -115,6 +134,9 @@ def mamba_apply(p, x, cfg, *, rules=None, cdt=jnp.bfloat16,
         return out, {"conv": conv_state, "ssm": s}
 
     # train/prefill: chunked associative scan
+    k = p["conv_w"].shape[0]
+    conv_state = jnp.pad(xin, ((0, 0), (max(k - 1 - S, 0), 0), (0, 0))
+                         )[:, -(k - 1):]
     xin, _ = _causal_conv(xin, p["conv_w"].astype(cdt),
                           p["conv_b"].astype(cdt))
     xin = jax.nn.silu(xin)
@@ -149,8 +171,10 @@ def mamba_apply(p, x, cfg, *, rules=None, cdt=jnp.bfloat16,
         y = (s * C_[:, :, None, :]).sum(-1)          # B,c,di
         return s[:, -1], y
 
+    # padded positions have dt = 0: exp(0 * A) = 1 and no input, so the
+    # state after the last chunk is the state after position S - 1
     s0 = jnp.zeros((B, di, h.d_state), jnp.float32)
-    _, ys = jax.lax.scan(chunk_step, s0, (xch, dtch, Bch, Cch))
+    s_last, ys = jax.lax.scan(chunk_step, s0, (xch, dtch, Bch, Cch))
     y = ys.transpose(1, 0, 2, 3).reshape(B, n_chunks * chunk, di)
     if pad:
         y = y[:, :S]
@@ -159,6 +183,8 @@ def mamba_apply(p, x, cfg, *, rules=None, cdt=jnp.bfloat16,
     if rules is not None:
         y = rules.constrain(y, "batch", None, "ffn")
     out = y @ p["out_proj"].astype(cdt)
+    if return_state:
+        return out, {"conv": conv_state, "ssm": s_last}
     return out, None
 
 

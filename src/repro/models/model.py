@@ -5,7 +5,8 @@
 
 * dense | moe | vlm : token-embedding decoder LM, scanned uniform layers.
 * hybrid (jamba)    : scanned periods of 1 attention + 7 mamba layers,
-                      MoE on even layers.
+                      MoE where ``cfg.moe.is_moe(slot)`` (Jamba: odd
+                      layers), a dense FFN on the others.
 * ssm (xlstm)       : scanned (mLSTM, sLSTM) block pairs.
 * audio (whisper)   : enc-dec; encoder over stubbed frame embeddings.
 
@@ -84,7 +85,7 @@ def _period_init(key, cfg):
     hb = cfg.hybrid
     ks = jax.random.split(key, 5)
     n_mamba = hb.period - 1
-    n_moe = sum(1 for s in range(hb.period) if s % cfg.moe.moe_every == 0)
+    n_moe = sum(1 for s in range(hb.period) if cfg.moe.is_moe(s))
     n_dense = hb.period - n_moe
     return {
         "attn": L.attention_init(ks[0], cfg),
@@ -132,7 +133,7 @@ def _period_apply(p, h, cfg, *, positions, rules, cdt, caches=None,
             mamba_i += 1
         h = h + a.astype(h.dtype)
         ffn_in = L.rms_norm(h, p["ln2"][slot], cfg.norm_eps)
-        if slot % cfg.moe.moe_every == 0:
+        if cfg.moe.is_moe(slot):
             ep = jax.tree.map(lambda x: x[moe_i], p["moe"])
             f, aux = M.moe_apply(ep, ffn_in, cfg, rules=rules, cdt=cdt)
             aux_total = aux_total + aux

@@ -8,6 +8,18 @@ an all-to-all-like collective under GSPMD.
 
 Active-FLOPs accounting: per token, top_k experts * capacity_factor slack,
 matching the 6*N_active*D convention used in the roofline.
+
+``capacity_factor=None`` is dropless: each expert gets one slot per token
+of the chunk, and since a token picks an expert at most once among its
+top-k, no expert can overflow. ``renormalize=False`` keeps the softmax
+weights of the top-k experts as they are, as Jamba's router does;
+Mixtral-style routers renormalize them.
+
+Expert parallelism: a layer whose expert weights hold ``n_held`` of the
+``n_experts`` (their leading axis, ``first_expert`` onward) still routes
+every token over all of them with the full router, and computes only
+its own experts' part of the output; the shares of the chips that hold
+the other experts add up to the whole layer.
 """
 from __future__ import annotations
 
@@ -45,16 +57,25 @@ def moe_axes(cfg):
 
 def _capacity(chunk_tokens: int, cfg) -> int:
     m = cfg.moe
+    if m.capacity_factor is None:
+        return chunk_tokens
     cap = int(np.ceil(
         chunk_tokens * m.top_k * m.capacity_factor / m.n_experts))
     return max(cap, m.top_k)
 
 
-def moe_apply(p, x, cfg, *, rules=None, cdt=jnp.bfloat16):
-    """x: (B, S, D) -> (out (B, S, D), aux_loss scalar)."""
+def moe_apply(p, x, cfg, *, rules=None, cdt=jnp.bfloat16,
+              first_expert: int = 0):
+    """x: (B, S, D) -> (out (B, S, D), aux_loss scalar).
+
+    ``p`` holds the experts ``first_expert`` .. ``first_expert + n_held
+    - 1`` (the leading axis of its expert weights); ``out`` is their
+    part of the layer's output."""
     B, S, D = x.shape
     m = cfg.moe
     E, K = m.n_experts, m.top_k
+    Eh = p["w_gate"].shape[0]
+    held = slice(first_expert, first_expert + Eh)
     chunk = min(MOE_CHUNK, S)
     n_chunks = S // chunk if S % chunk == 0 else -(-S // chunk)
     pad = n_chunks * chunk - S
@@ -68,12 +89,14 @@ def moe_apply(p, x, cfg, *, rules=None, cdt=jnp.bfloat16):
         logits = (h @ p["router"].astype(cdt)).astype(jnp.float32)  # B,c,E
         probs = jax.nn.softmax(logits, axis=-1)
         topv, topi = jax.lax.top_k(probs, K)                         # B,c,K
-        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+        if m.renormalize:
+            topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+        onehot_all = jax.nn.one_hot(topi, E, dtype=jnp.float32)      # B,c,K,E
+        onehot = onehot_all[..., held]                               # B,c,K,Eh
         # slot position of each (token, k) within its expert, via cumsum
-        onehot = jax.nn.one_hot(topi, E, dtype=jnp.float32)          # B,c,K,E
-        flat = onehot.reshape(B, chunk * K, E)
-        pos = jnp.cumsum(flat, axis=1) - flat                        # B,cK,E
-        pos = pos.reshape(B, chunk, K, E)
+        flat = onehot.reshape(B, chunk * K, Eh)
+        pos = jnp.cumsum(flat, axis=1) - flat                        # B,cK,Eh
+        pos = pos.reshape(B, chunk, K, Eh)
         slot = (pos * onehot).sum(-1)                                # B,c,K
         keep = slot < cap
         slot_oh = jax.nn.one_hot(jnp.where(keep, slot, cap), cap + 1,
@@ -95,7 +118,7 @@ def moe_apply(p, x, cfg, *, rules=None, cdt=jnp.bfloat16):
         out = jnp.einsum("bcep,ebpd->bcd", comb.astype(cdt), eout)   # B,c,D
         # load-balance aux (Switch-style): mean prob * mean assigned fraction
         me = probs.mean(axis=(0, 1))                                 # E
-        ce = onehot.mean(axis=(0, 1, 2)) * K                         # E
+        ce = onehot_all.mean(axis=(0, 1, 2)) * K                     # E
         aux = (me * ce).sum() * E
         return out, aux
 

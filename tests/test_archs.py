@@ -113,7 +113,8 @@ def test_prefill_decode_consistency(name, reduced_state):
 
 def test_param_counts_match_init():
     """Analytic param_count ~= actual init sizes (within vocab padding)."""
-    for name in ["qwen3-0.6b", "qwen3-1.7b", "starcoder2-3b"]:
+    for name in ["qwen3-0.6b", "qwen3-1.7b", "starcoder2-3b",
+                 "jamba2-mini", "jamba-v0.1-52b"]:
         cfg = get_arch(name)
         abs_p = STEPS.abstract_params(cfg)
         actual = sum(int(np.prod(x.shape))
@@ -133,8 +134,14 @@ def test_full_configs_are_exact():
     assert p.moe.n_experts == 16 and p.moe.top_k == 2
     g = get_arch("granite-moe-1b-a400m")
     assert g.moe.n_experts == 32 and g.moe.top_k == 8
-    j = get_arch("jamba-v0.1-52b")
-    assert j.hybrid.period == 8 and j.moe.moe_every == 2
+    for jname in ("jamba-v0.1-52b", "jamba2-mini"):
+        j = get_arch(jname)
+        assert j.hybrid.period == 8 and j.moe.moe_every == 2
+        # expert_layer_offset 1: MoE on the odd layers, attention at 4
+        assert [i for i in range(8) if j.moe.is_moe(i)] == [1, 3, 5, 7]
+        assert j.hybrid.attn_index == 4 and not j.rope
+        assert j.moe.capacity_factor is None and not j.moe.renormalize
+        assert j.hybrid.dt_rank == 256 and j.norm_eps == 1e-6
     x = get_arch("xlstm-125m")
     assert x.d_ff == 0 and x.n_heads == 4
 

@@ -261,3 +261,71 @@ def test_train_step_span_layout(tmp_path):
         assert (a["chunks"], a["positions"]) == (chunks, positions)
         assert (a["rows"], a["width"]) == shape
         assert 0 < a["tokens"] <= a["positions"] <= a["rows"] * a["width"]
+
+
+def _profile(tmp_path, fn):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return _events(tmp_path)
+
+
+def test_frontdoor_parse_span(tmp_path):
+    """Each ingested text marks one ``frontdoor.parse`` span whose
+    arguments are what the parse recovered: its ops, its loops and
+    those of unknown trip count, and the lines it lost."""
+    from repro.ir import frontdoor as FD
+
+    @jax.jit
+    def inner(x):
+        return jax.nn.relu(x) * 2.0
+
+    def fn(x, n):
+        x = jax.lax.fori_loop(0, 5, lambda i, a: a + 1.0, x)
+        x = jax.lax.fori_loop(0, n, lambda i, a: a * 0.5, x)
+        return inner(x)
+    text = jax.jit(fn).lower(jax.ShapeDtypeStruct((8,), np.float32),
+                             jax.ShapeDtypeStruct((), np.int32)).as_text()
+    _, st = FD.parse_mlir_stats(text)
+    assert (st.whiles, st.unknown_trips, st.lossy) == (2, 1, 0)
+    events = _profile(tmp_path, lambda: [FD.ingest(text) for _ in range(2)])
+    spans = _named(events, "frontdoor.parse")
+    assert len(spans) == 2
+    for e in spans:
+        assert {k: e["args"][k] for k in
+                ("ops", "whiles", "unknown_trips", "lossy")} == {
+            "ops": st.ops, "whiles": 2, "unknown_trips": 1, "lossy": 0}
+
+
+def test_featurize_span_counts_truncation(corpus, tmp_path):
+    """``server.featurize`` marks ``truncated`` on a row whose tokens
+    were cut at the model's ``max_seq``, and 0 on the others and on
+    cache hits."""
+    graphs, vocab = corpus
+    cfg = CostModelConfig(name="span-test", vocab_size=512, max_seq=32,
+                          embed_dim=16, conv_channels=(16,) * 3,
+                          fc_dims=(32, 16))
+    params = CM.conv_init(jax.random.PRNGKey(0), cfg,
+                          heads=CM.DEFAULT_HEADS)
+    stats = {t: {"mu": 0.3, "sigma": 1.7} for t in CM.DEFAULT_HEADS}
+    svc = CostModelService("conv1d", cfg, params, vocab, stats,
+                           mode="ops", max_seq=32, max_batch=8)
+    server = CostModelServer(svc, max_batch=8, flush_us=500)
+    server.start(warmup=False)
+    try:
+        events = _profile(tmp_path, lambda: [server.predict_all(graphs)
+                                             for _ in range(2)])
+    finally:
+        server.stop()
+    spans = _named(events, "server.featurize")
+    fresh = [e["args"]["truncated"] for e in spans if not e["args"]["hit"]]
+    hits = [e["args"]["truncated"] for e in spans if e["args"]["hit"]]
+    cut = sum(len(TOK.graph_tokens(g, "ops")) > 32 for g in graphs)
+    assert 0 < cut < len(graphs)
+    assert len(fresh) == len(graphs) and sum(fresh) == cut
+    assert len(hits) == len(graphs) and not any(hits)
+    assert svc.truncations == cut
