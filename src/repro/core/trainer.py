@@ -19,8 +19,14 @@ full substrate every time:
   applies AdamW: the whole batch's update over fewer pad positions.
   Every chunk shape is compiled before step 1. A batch the loader
   leaves whole runs the one fused ``train_step`` program;
-* mesh + :class:`~repro.runtime.sharding.ShardingRules` (params are placed
-  by the per-family logical axis tables when the mesh has >1 device);
+* data parallelism on a mesh of more than one device: every parameter
+  (and so the AdamW moments) is whole on each chip of the ``data`` axis
+  and each batch is split there by rows, so each chip computes its own
+  rows' gradient (:meth:`TrainEngine.param_shardings`; the ``model``
+  axis keeps the per-family logical axis tables of
+  :class:`~repro.runtime.sharding.ShardingRules`). A split batch's
+  chunk programs keep each chip's partial gradient and ``apply_grads``
+  sums chunks and chips in one exchange a step;
 * optional int8 error-feedback gradient compression on the DP axis;
 * a :class:`~repro.runtime.fault.TrainSupervisor` step loop: periodic +
   on-preemption atomic checkpoints carrying the loader cursor, and
@@ -108,22 +114,48 @@ def train_step(apply_fn, opt_cfg, heads, state, ids, yy):
 def chunk_grad(apply_fn, heads, params, ids, yy, start):
     """One chunk's float32 gradient and loss, each weighted by the
     chunk's share of the batch's rows; the chunk's labels are
-    ``yy[start:start + rows]``."""
+    ``yy[start:start + rows]``. On a mesh whose ``data`` axis holds n > 1
+    devices each device keeps the gradient of its own rows: gradient and
+    loss then carry a leading axis of the n partials, split along
+    ``data``, and :func:`apply_grads` sums them."""
     rows = ids.shape[0]
     y = jax.lax.dynamic_slice_in_dim(yy, start, rows)
-    loss, grads = jax.value_and_grad(make_loss_fn(apply_fn, heads))(
-        params, ids, y)
+    grad_fn = jax.value_and_grad(make_loss_fn(apply_fn, heads))
+
+    def weighted(params, ids, y, w):
+        loss, grads = grad_fn(params, ids, y)
+        return (jax.tree.map(lambda g: w * g.astype(jnp.float32), grads),
+                w * loss)
+
     w = rows / yy.shape[0]
-    return (jax.tree.map(lambda g: w * g.astype(jnp.float32), grads),
-            w * loss)
+    mesh = jax.typeof(ids).sharding.mesh
+    n = 1 if mesh.empty else mesh.shape.get("data", 1)
+    if n == 1:
+        return weighted(params, ids, y, w)
+
+    def local(params, ids, y):          # a device's rows: 1/n of the chunk
+        grads, loss = weighted(params, ids, y, w / n)
+        return jax.tree.map(lambda g: g[None], grads), loss[None]
+    # check_vma off: the families' scans start from unvarying zeros
+    return jax.shard_map(local, mesh=mesh, axis_names={"data"},
+                         in_specs=(P(), P("data"), P("data")),
+                         out_specs=P("data"), check_vma=False)(
+                             params, ids, y)
 
 
 @partial(jax.jit, static_argnums=0)
 def apply_grads(opt_cfg, state, chunks):
     """The whole batch's update and loss from its chunks' weighted
-    gradients and losses, summed here."""
+    gradients and losses, summed here: over the chunks, then over the
+    devices' partials where the chunks carry them, so a mesh exchanges
+    gradients once a step."""
     grads = jax.tree.map(lambda *g: sum(g), *[g for g, _ in chunks])
-    return _update(opt_cfg, state, grads), sum(loss for _, loss in chunks)
+    split = chunks[0][1].ndim > 0
+    if split:
+        grads = jax.tree.map(lambda g: g.sum(0), grads)
+    state = _update(opt_cfg, state, grads)
+    loss = sum(loss for _, loss in chunks)
+    return state, loss.sum() if split else loss
 
 
 class TrainStep:
@@ -264,6 +296,21 @@ class TrainEngine:
         return {k: v.map(put) if isinstance(v, PIPE.Chunks) else put(v)
                 for k, v in batch.items()}
 
+    def param_shardings(self, mesh, params):
+        """Each parameter's sharding on ``mesh``: the model family's
+        logical axis tables, with nothing on ``data``. The default rules
+        split ``embed`` over ``data`` (FSDP, for the 30-50B language
+        models ``launch/dryrun.py`` lowers); a cost model's whole train
+        state is under 1 GB, and with its embedding table split by
+        features the TPU compiler gathers each chunk's embeddings and
+        runs every convolution on all of the chunk's rows on every
+        chip."""
+        rules = ShardingRules(mesh, overrides={"embed": None})
+        axes = self.axes_fn(self.cfg, heads=self.heads) if self.heads \
+            else self.axes_fn(self.cfg)
+        return tree_shardings(rules, axes,
+                              jax.tree.map(lambda x: x.shape, params))
+
     def compile_chunks(self, loader: PIPE.Loader, state, opt_cfg,
                        y: np.ndarray) -> None:
         """Compile (or load from the persistent cache) every chunk
@@ -298,19 +345,13 @@ class TrainEngine:
         loader = self.make_loader(train, y)
 
         # Auto axes: params and batch carry explicit NamedShardings and
-        # GSPMD propagates the rest (JAX's default Explicit axes would
-        # reject e.g. the emb gather, whose table dim and batch dim both
-        # claim "data")
+        # GSPMD propagates the rest
         mesh = self.mesh = jax.make_mesh(
             (e.mesh_data, e.mesh_model), ("data", "model"),
             axis_types=(AxisType.Auto, AxisType.Auto))
         if mesh.devices.size > 1:
-            rules = ShardingRules(mesh)
-            axes = self.axes_fn(self.cfg, heads=self.heads) if self.heads \
-                else self.axes_fn(self.cfg)
-            shapes = jax.tree.map(lambda x: x.shape, params)
             params = jax.tree.map(jax.device_put, params,
-                                  tree_shardings(rules, axes, shapes))
+                                  self.param_shardings(mesh, params))
 
         opt_cfg = adamw.AdamWConfig(lr=e.lr, total_steps=e.steps,
                                     warmup_steps=min(50, e.steps // 10),

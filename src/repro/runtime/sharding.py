@@ -38,6 +38,8 @@ DEFAULT_RULES: Dict[str, Tuple[str, ...]] = {
                                 # + optimizer states fit 16 GB/chip; on
                                 # activations the batch dim claims "data"
                                 # first, so h stays batch-sharded
+                                # (TrainEngine overrides it: a cost model
+                                # keeps its params whole along "data")
     "seq":     (),              # train/prefill seq: context-parallel override
     "cache_seq": ("model",),    # decode KV-cache sequence dim
     "qseq":    ("model",),      # query-seq context parallelism: picks up the

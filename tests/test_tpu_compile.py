@@ -20,9 +20,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (AxisType, Mesh, NamedSharding,
+                          PartitionSpec as P, SingleDeviceSharding)
 
-from repro.configs.costmodel import COSTMODEL_BASE
+from repro.configs.costmodel import COSTMODEL_100M, COSTMODEL_BASE
 from repro.core import models as CM
 from repro.core import trainer as TR
 from repro.kernels import ops as KOPS
@@ -166,3 +167,71 @@ def test_chunk_programs_compile_for_v5e(one_chip):
         (params, jax.eval_shape(adamw.init_state, params), None), one_chip)
     update = TR.apply_grads.lower(opt_cfg, state, (chunk,) * 4).compile()
     assert update.memory_analysis() is not None
+
+
+def _flops(compiled):
+    cost = compiled.cost_analysis()
+    return (cost[0] if isinstance(cost, list) else cost)["flops"]
+
+
+def _elements(shapes):
+    """Element counts of the array shapes in an HLO result type."""
+    return [int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in re.findall(r"\w+\[([\d,]*)\]", shapes)]
+
+
+def test_data_parallel_chunk_splits_rows_for_v5e(topo, one_chip):
+    """A ``mesh_data=4`` engine's chunk program at ``COSTMODEL_100M``
+    (a 16x1024 chunk of a batch of 64, three heads) on a 2x2 v5e: each
+    chip computes its own 4 rows, at most 0.3x the FLOPs of the whole
+    chunk on one chip, gathers nothing larger than the labels and sums
+    nothing across chips; the update exchanges the gradient once. The
+    CPU partitioner splits the rows under any parameter placement; the
+    TPU compiler replicates every convolution on every chip when the
+    embedding table is split by features."""
+    cfg, heads = COSTMODEL_100M, CM.DEFAULT_HEADS
+    eng = TR.TrainEngine("conv1d", cfg, heads, mesh_data=4)
+    params = jax.eval_shape(
+        lambda: eng.init_fn(jax.random.PRNGKey(0), cfg, heads=heads))
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"),
+                axis_types=(AxisType.Auto, AxisType.Auto))
+    shardings = eng.param_shardings(mesh, params)
+    assert all("data" not in str(s.spec)
+               for s in jax.tree.leaves(shardings))
+    rows = NamedSharding(mesh, P("data"))
+    labels = (64, len(heads))
+    placed = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        params, shardings)
+    split = TR.chunk_grad.lower(
+        eng.apply_fn, heads, placed, _ids(rows, 16, 1024),
+        jax.ShapeDtypeStruct(labels, np.float32, sharding=rows),
+        0).compile()
+    whole = TR.chunk_grad.lower(
+        eng.apply_fn, heads, _abstract(params, one_chip),
+        _ids(one_chip, 16, 1024),
+        jax.ShapeDtypeStruct(labels, np.float32, sharding=one_chip),
+        0).compile()
+    assert _flops(split) <= 0.3 * _flops(whole), \
+        (_flops(split), _flops(whole))
+    gathers = re.findall(r"= (.*?) all-gather(?:-start)?\(",
+                         split.as_text())
+    assert all(n <= np.prod(labels) for g in gathers
+               for n in _elements(g)), gathers
+    # the chips keep their partial gradients; the update sums them in
+    # one exchange of the whole gradient (and the loss) a step
+    assert "all-reduce" not in split.as_text()
+    chunk = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows),
+        jax.eval_shape(lambda: TR.chunk_grad(
+            eng.apply_fn, heads, placed, _ids(rows, 16, 1024),
+            jax.ShapeDtypeStruct(labels, np.float32, sharding=rows), 0)))
+    state = _abstract(
+        (params, jax.eval_shape(adamw.init_state, params), None),
+        NamedSharding(mesh, P()))
+    opt_cfg = adamw.AdamWConfig(lr=1e-3, total_steps=40, warmup_steps=4)
+    update = TR.apply_grads.lower(opt_cfg, state, (chunk,) * 4).compile()
+    exchanged = sum(sum(_elements(r)) for r in re.findall(
+        r"= (.*?) all-reduce(?:-start)?\(", update.as_text()))
+    n_params = sum(p.size for p in jax.tree.leaves(params))
+    assert n_params <= exchanged <= n_params + 1, (exchanged, n_params)

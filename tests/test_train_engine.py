@@ -239,6 +239,68 @@ def test_engine_data_parallel_shards_batch_and_matches(kind):
     np.testing.assert_allclose(out["4"], out["1"], rtol=1e-5, atol=1e-6)
 
 
+_DP_PLACE_SCRIPT = """
+import json, re, sys
+import jax
+import numpy as np
+from repro.configs import COSTMODEL_SMALL
+from repro.core import models as CM
+from repro.core import trainer as TR
+from repro.ir import dataset as DS
+from repro.optim import adamw
+
+ds = DS.build_dataset(200, mode="ops", max_seq=64, vocab_size=512,
+                      augment_factor=1, seed=3)
+out = {}
+for md in (4, 1):
+    eng = TR.TrainEngine(sys.argv[1], COSTMODEL_SMALL, CM.DEFAULT_HEADS,
+                         steps=1, batch_size=16, mesh_data=md)
+    res = eng.fit(ds)
+    out[md] = [np.asarray(p).ravel().tolist()
+               for p in jax.tree.leaves(res.params)]
+    if md == 4:
+        placed = eng.param_shardings(eng.mesh, res.params)
+        out["specs"] = sorted({str(s.spec) for s in
+                               jax.tree.leaves(placed)} |
+                              {str(p.sharding.spec) for p in
+                               jax.tree.leaves(res.params)})
+        b = eng.place_batch({"ids": np.ones((16, 32), np.int32),
+                             "y": np.zeros((16, 3), np.float32)})
+        args = (eng.apply_fn, eng.heads, res.params, b["ids"], b["y"], 0)
+        chunk = TR.chunk_grad(*args)
+        state = (res.params, adamw.init_state(res.params), None)
+        opt = adamw.AdamWConfig(lr=1e-3, total_steps=4, warmup_steps=1)
+        for name, fn, a in (
+                ("chunk", TR.chunk_grad, args),
+                ("apply", TR.apply_grads, (opt, state, (chunk,) * 4))):
+            text = fn.lower(*a).compile().as_text()
+            out[name] = sum(
+                int(np.prod([int(d) for d in dims.split(",") if d]))
+                for shapes in re.findall(
+                    r"= (.*?) all-reduce(?:-start)?\\(", text)
+                for dims in re.findall(r"\\w+\\[([\\d,]*)\\]", shapes))
+        out["n_params"] = sum(p.size for p in jax.tree.leaves(res.params))
+print(json.dumps(out))
+"""
+
+
+@pytest.mark.parametrize("kind", ["conv1d", "xformer"])
+def test_engine_data_parallel_keeps_params_whole(kind):
+    """mesh_data=4 on 4 virtual CPU devices: no parameter is split
+    along ``data``, as placed or as the step hands it back, and one
+    data-parallel step's updated parameters match one device's. The
+    xformer puts the ``embed`` axis on most of its weights. A chunk's
+    gradient program exchanges nothing; the update sums the chunks'
+    partials in one exchange of the whole gradient (and the loss)."""
+    out = _child(_DP_PLACE_SCRIPT, kind)
+    assert out["specs"] and not any("data" in s for s in out["specs"])
+    assert out["chunk"] == 0
+    assert out["n_params"] <= out["apply"] <= out["n_params"] + 1
+    assert len(out["4"]) == len(out["1"])
+    for a, b in zip(out["4"], out["1"]):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
 # ------------------------------------------------- width-sorted chunks
 FAMILY_CFG = dataclasses.replace(COSTMODEL_SMALL, max_seq=128)
 HEADS = ("register_pressure", "valu_utilization", "latency_us")
