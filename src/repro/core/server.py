@@ -303,23 +303,16 @@ class CostModelServer:
 
         Fast paths: an LRU hit resolves immediately without queueing —
         probed by struct key BEFORE any tokenization, so a hit never
-        lexes the graph at all (``fast_encode`` services; the legacy
-        path encodes first, as before); a request whose content hash is
-        already in flight coalesces onto the pending compute. A full
-        queue sheds the request instead."""
+        lexes the graph at all; a request whose content hash is already
+        in flight coalesces onto the pending compute. A full queue sheds
+        the request instead."""
         if not self._running:
             raise RuntimeError("server not started (call start())")
         with annotate("server.featurize") as sp:
-            if self.service.fast_encode:
-                key = self.service.key_of(g)
-                hit = self.service.cache_lookup(key)
-                ids = None if hit is not None else \
-                    self.service.ids_for(g, key)
-            else:
-                key, ids = self.service.entry(g)
-                hit = self.service.cache_lookup(key)
-                if hit is not None:
-                    ids = None
+            key = self.service.key_of(g)
+            hit = self.service.cache_lookup(key)
+            ids = None if hit is not None else \
+                self.service.ids_for(g, key)
             if sp is not None:
                 sp.set_metadata(hit=int(hit is not None), truncated=int(
                     ids is not None and self.service.truncated(key)))

@@ -14,9 +14,9 @@ while compiling in order to make the best decisions." This module provides:
 * a bounded LRU prediction cache (per-target vectors keyed by content
   hash) so a long-running compiler session can't grow memory without
   limit.
-* an incremental featurization hot path (``fast_encode``, default): the
-  LRU is probed by struct key BEFORE any tokenization, token-id arrays
-  are cached by struct key, rewrite-derived graphs splice their ids
+* an incremental featurization hot path: the LRU is probed by struct
+  key BEFORE any tokenization, token-id arrays are cached by struct
+  key, rewrite-derived graphs splice their ids
   from the parent's cached array (only the rewrite's dirty ops are
   re-lexed), and fresh batches encode through the vectorized
   ``Vocab.encode_many``. Phase timers (``phase_stats()``) attribute
@@ -26,8 +26,8 @@ while compiling in order to make the best decisions." This module provides:
   once at construction, forward passes run bf16 over the same
   (bucket x ladder) program set (so ``warmup()`` covers them), and rows
   widen to float32 before the LRU so denormalization stays
-  float32-exact. Drift vs f32 is gated in tests and the search_fleet
-  benchmark (Spearman >= 0.99 per target).
+  float32-exact. Drift vs f32 is gated in tests (Spearman >= 0.99 per
+  target).
 * three compiler advisors built on top of it — since PR 4 each is a thin
   wrapper over a single-rule ``repro.opt`` search (the full multi-rule
   beam search lives in :mod:`repro.opt.search`):
@@ -100,12 +100,6 @@ class CostModelService:
     # before the LRU and denormalize, so the denormalize path stays
     # float32-exact). Prediction drift vs f32 is gated in tests.
     dtype: str = "f32"
-    # Hot-path featurization: token-id arrays cached by struct_key,
-    # parent-delta tokenization for rewrite-derived graphs, vectorized
-    # Vocab.encode_many for fresh batches, and LRU probes by key BEFORE
-    # any tokenization. False restores the legacy always-re-lex path —
-    # the flag-switchable baseline the search_fleet benchmark measures.
-    fast_encode: bool = True
     ids_cache_size: int = 8192
     # Serve through the fused Pallas forward (kernels/ops.forward_apply;
     # interpret mode on the CPU backend, compiled everywhere else, with
@@ -113,7 +107,7 @@ class CostModelService:
     # and runs tower + FC + heads in one pallas_call; lstm runs the
     # VMEM-carry recurrence kernel. Composes with dtype="bf16": kernels
     # read bf16 params but accumulate f32 in-kernel (drift vs f32 is
-    # Spearman-gated in tests and kernel_bench). The kernels'
+    # Spearman-gated in tests). The kernels'
     # accumulation order differs from XLA's, so f32 parity is
     # "allclose", not bit-identical.
     use_kernel: bool = False
@@ -308,13 +302,6 @@ class CostModelService:
                 self.truncations += 1
         return self.vocab.encode(toks, bucket), len(toks)
 
-    def _encode(self, g: Graph) -> np.ndarray:
-        """Token ids padded to the graph's bucket, not the global max_seq."""
-        t0 = time.perf_counter()
-        ids, _ = self._fresh_ids(g)
-        self._phase_add("encode_s", time.perf_counter() - t0)
-        return ids
-
     def _delta_ids(self, g: Graph) -> Optional[Tuple[np.ndarray, int]]:
         """Splice a rewrite-derived graph's token ids from its parent's
         cached ids: copied op spans are gathered with one vectorized
@@ -394,10 +381,7 @@ class CostModelService:
 
     def ids_for(self, g: Graph, key: str) -> np.ndarray:
         """Bucket-padded token ids for one graph: ids-cache probe, then
-        the parent-delta splice, then a from-scratch encode (legacy
-        behavior — and the whole path when ``fast_encode=False``)."""
-        if not self.fast_encode:
-            return self._encode(g)
+        the parent-delta splice, then a from-scratch encode."""
         ids = self._ids_cache_get(key)
         if ids is not None:
             return ids
@@ -749,44 +733,29 @@ class CostModelService:
         """All targets for every graph from one cached, batched, bucketed
         forward pass. Returns {target: (len(graphs),) denormalized array}.
 
-        Fast path (``fast_encode``, default): the prediction LRU is
-        probed by struct key FIRST — cache hits and in-call duplicates
-        never tokenize at all — and the remaining misses featurize
-        through the ids cache / parent-delta splice / batched
-        ``encode_many``. The legacy path (``fast_encode=False``)
-        tokenizes and encodes every graph before probing, exactly the
-        pre-incremental behavior (the search_fleet baseline)."""
+        The prediction LRU is probed by struct key FIRST — cache hits and
+        in-call duplicates never tokenize at all — and the remaining
+        misses featurize through the ids cache / parent-delta splice /
+        batched ``encode_many``."""
         if not graphs:
             return {t: np.zeros((0,), np.float32) for t in self.heads}
         keys: List[str] = []
         vals: Dict[str, np.ndarray] = {}   # this call's working set: the
         missing: Dict[str, np.ndarray] = {}  # LRU may evict entries mid-call
-        if self.fast_encode:
-            miss_graphs: Dict[str, Graph] = {}
-            for g in graphs:
-                h = self.key_of(g)
-                keys.append(h)
-                if h in vals or h in miss_graphs:
-                    continue
-                hit = self.cache_lookup(h)
-                if hit is not None:
-                    vals[h] = hit
-                else:
-                    miss_graphs[h] = g
-            if miss_graphs:
-                missing = dict(self.entries_for(
-                    list(miss_graphs.values()), list(miss_graphs)))
-        else:
-            for g in graphs:
-                h, ids = self.entry(g)
-                keys.append(h)
-                if h in vals or h in missing:
-                    continue
-                hit = self.cache_lookup(h)
-                if hit is not None:
-                    vals[h] = hit
-                else:
-                    missing[h] = ids
+        miss_graphs: Dict[str, Graph] = {}
+        for g in graphs:
+            h = self.key_of(g)
+            keys.append(h)
+            if h in vals or h in miss_graphs:
+                continue
+            hit = self.cache_lookup(h)
+            if hit is not None:
+                vals[h] = hit
+            else:
+                miss_graphs[h] = g
+        if miss_graphs:
+            missing = dict(self.entries_for(
+                list(miss_graphs.values()), list(miss_graphs)))
         if missing:
             # group by bucket length: one jitted program per bucket
             by_len: Dict[int, List[Tuple[str, np.ndarray]]] = {}

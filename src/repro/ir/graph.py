@@ -14,27 +14,6 @@ import numpy as np
 
 Shape = Tuple[int, ...]
 
-# Incremental structural hashing (see Graph.struct_key): rewrite-derived
-# graphs inherit the per-value hashes of ops copied verbatim from their
-# parent, so only the rewrite's dirty cone is re-hashed. Disable to force
-# every struct_key() call back to the full from-scratch Merkle walk (the
-# pre-incremental behavior) — the flag-switchable baseline the
-# ``search_fleet`` benchmark measures against.
-_INCREMENTAL_HASHING = True
-
-
-def set_incremental_hashing(enabled: bool) -> bool:
-    """Toggle incremental struct_key hashing; returns the previous value."""
-    global _INCREMENTAL_HASHING
-    prev = _INCREMENTAL_HASHING
-    _INCREMENTAL_HASHING = bool(enabled)
-    return prev
-
-
-def incremental_hashing_enabled() -> bool:
-    return _INCREMENTAL_HASHING
-
-
 @dataclass(frozen=True)
 class Tensor:
     shape: Shape
@@ -190,8 +169,6 @@ class Graph:
         identically, so they splice. No reference to ``parent`` is kept
         — hashes resolve eagerly and the token hint is keyed by the
         parent's struct key."""
-        if not _INCREMENTAL_HASHING:
-            return
         ph = parent.value_hashes()
         self._inherited = {cv: ph[pv] for cv, pv in copied.items()}
         self._vhash = None
@@ -221,8 +198,6 @@ class Graph:
         contract, and every rewrite builds a fresh graph). Rewrite-derived
         graphs inherit per-value hashes for verbatim-copied ops
         (:meth:`adopt_hashes`), so only the dirty cone is re-hashed."""
-        if not _INCREMENTAL_HASHING:
-            return self.struct_key_fresh()
         token = (len(self.ops), self.n_args, tuple(self.outputs))
         if self._key_cache is not None and self._key_cache[0] == token:
             return self._key_cache[1]
@@ -233,8 +208,7 @@ class Graph:
     def struct_key_fresh(self) -> str:
         """From-scratch reference walk: ignores every memo and inherited
         hash (and caches nothing). The invariant incremental hashing must
-        preserve — property tests compare against this — and the whole
-        behavior when ``set_incremental_hashing(False)``."""
+        preserve; property tests compare against this."""
         return self._combine_key(self._compute_hashes({}))
 
 

@@ -141,17 +141,3 @@ def forward_apply(kind: str, params, ids, *,
         return lstm_forward_apply(params, ids, interpret=interpret)
     raise ValueError(
         f"use_kernel supports kinds {KERNEL_KINDS}, not {kind!r}")
-
-
-def fused_forward_bytes(params, batch: int, seq: int) -> int:
-    """Modeled HBM traffic of one fused conv forward: ids, the XLA
-    gather (embedding rows read, ``(B, S, E)`` activations written and
-    read back by the kernel), one read of every other param, and the
-    predictions. Used by the kernel_bench roofline."""
-    names = model_heads(params)
-    n_heads = len(names) if names else 1
-    emb = params["emb"]
-    act = batch * seq * emb.shape[1] * emb.dtype.itemsize
-    p_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
-    p_bytes -= emb.size * emb.dtype.itemsize   # only gathered rows are read
-    return batch * seq * 4 + 3 * act + p_bytes + batch * n_heads * 4

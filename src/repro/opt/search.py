@@ -250,18 +250,3 @@ def greedy_search(service, g: Graph,
     """Cheap mode: beam of 1, stop as soon as no candidate improves."""
     kw.setdefault("max_steps", 8)
     return beam_search(service, g, rules, greedy=True, **kw)
-
-
-def search_pool(service, pool: Sequence[Graph], offset: int = 0,
-                **search_kw) -> List[SearchResult]:
-    """One fleet-worker pass: beam-search every graph in ``pool``,
-    rotated by ``offset`` so concurrent workers traverse the same pool
-    out of phase (maximizing in-flight coalescing and cross-search LRU
-    hits without ever searching the same graph simultaneously).
-
-    ``service`` is anything beam_search can cost through — an in-process
-    CostModelService, an async CostModelServer gateway, or a replicated
-    :class:`~repro.serving.router.ReplicaClient`."""
-    k = offset % len(pool) if pool else 0
-    gs = list(pool[k:]) + list(pool[:k])
-    return [beam_search(service, g, **search_kw) for g in gs]

@@ -21,9 +21,9 @@ AxisRule = Union[None, str, Tuple[str, ...]]
 # Default logical->mesh rules. 'pod' composes with 'data' for the batch dim
 # so the same table serves single-pod (no 'pod' axis) and multi-pod meshes.
 DEFAULT_RULES: Dict[str, Tuple[str, ...]] = {
-    # batch spreads over the model axis too when divisible (wide DP): the
-    # §Perf hillclimb showed per-layer TP activation collectives dominate
-    # train steps at every model size (0.6B..52B), while weight gathers
+    # batch spreads over the model axis too when divisible (wide DP):
+    # per-layer TP activation collectives would dominate train steps at
+    # every model size (0.6B..52B), while weight gathers
     # (FSDP, from the 2D param sharding below) are smaller and overlappable.
     # Smaller batches (prefill 32, decode 128) gracefully fall back to
     # data-only sharding via the divisibility resolver.

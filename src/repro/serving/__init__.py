@@ -23,8 +23,6 @@ Layers (bottom up):
 * :mod:`repro.serving.faults` — seeded :class:`FaultPlan` /
   :class:`FaultyTransport`, the deterministic chaos harness behind
   the ``chaos_serve`` gate.
-* :mod:`repro.serving.fleet` — :class:`FleetDriver`, the multi-process
-  fleet-client harness the replicated search bench drives.
 """
 from repro.serving.faults import FaultEvent, FaultPlan, FaultyTransport
 from repro.serving.replica import ReplicaTier, TierHandle, start_replicas
@@ -33,12 +31,10 @@ from repro.serving.shared_cache import SharedRowCache
 from repro.serving.supervisor import (ReplicaSupervisor, RestartBudget,
                                       ScalePolicy)
 from repro.serving.transport import ServiceSpec
-from repro.serving.fleet import FleetDriver, fleet_worker_main
 
 __all__ = [
-    "FaultEvent", "FaultPlan", "FaultyTransport", "FleetDriver",
-    "HashRing", "QueueTransport", "ReplicaClient", "ReplicaSupervisor",
-    "ReplicaTier", "RestartBudget", "ScalePolicy", "ServiceSpec",
-    "SharedRowCache", "TierHandle", "fleet_worker_main",
-    "start_replicas",
+    "FaultEvent", "FaultPlan", "FaultyTransport", "HashRing",
+    "QueueTransport", "ReplicaClient", "ReplicaSupervisor", "ReplicaTier",
+    "RestartBudget", "ScalePolicy", "ServiceSpec", "SharedRowCache",
+    "TierHandle", "start_replicas",
 ]

@@ -78,7 +78,6 @@ class ServiceSpec:
     max_batch: int = 256
     cache_size: int = 4096
     dtype: str = "f32"
-    fast_encode: bool = True
     use_kernel: bool = False
     buckets: Optional[Tuple[int, ...]] = None
     batch_ladder: Optional[Tuple[int, ...]] = None
@@ -99,8 +98,7 @@ class ServiceSpec:
                    vocab=svc.vocab, norm_stats=svc.norm_stats,
                    mode=svc.mode, max_seq=svc.max_seq,
                    max_batch=svc.max_batch, cache_size=svc.cache_size,
-                   dtype=svc.dtype, fast_encode=svc.fast_encode,
-                   use_kernel=svc.use_kernel,
+                   dtype=svc.dtype, use_kernel=svc.use_kernel,
                    buckets=tuple(svc.buckets),
                    batch_ladder=tuple(svc.batch_ladder),
                    n_unk_buckets=getattr(svc.vocab, "n_unk_buckets", 0),
@@ -127,9 +125,9 @@ class ServiceSpec:
                           byte_fallback=self.byte_fallback)
         kw = dict(mode=self.mode, max_seq=self.max_seq,
                   max_batch=self.max_batch, cache_size=self.cache_size,
-                  dtype=self.dtype, fast_encode=self.fast_encode,
-                  use_kernel=self.use_kernel, buckets=self.buckets,
-                  batch_ladder=self.batch_ladder, **self.extra)
+                  dtype=self.dtype, use_kernel=self.use_kernel,
+                  buckets=self.buckets, batch_ladder=self.batch_ladder,
+                  **self.extra)
         kw.update(overrides)
         return CostModelService(self.kind, self.cfg, params,
                                 vocab, self.norm_stats, **kw)

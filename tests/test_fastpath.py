@@ -14,7 +14,6 @@ from repro.core import models as CM
 from repro.core import tokenizer as TOK
 from repro.core.server import CostModelServer
 from repro.core.service import CostModelService
-from repro.ir import graph as IRG
 from repro.ir import samplers
 from repro.ir.graph import Graph, Tensor
 from repro.opt import rewrites as RW
@@ -39,10 +38,17 @@ def _mk_service(**kw):
 
 
 @pytest.fixture(scope="module")
-def fast_and_legacy():
-    fast, graphs = _mk_service()
-    legacy, _ = _mk_service(fast_encode=False)
-    return fast, legacy, graphs
+def fast_service():
+    return _mk_service()
+
+
+def _scratch_predictions(svc, graphs):
+    """Per-graph reference: from-scratch ids, one forward each, and the
+    service's denormalize — no LRU probe, ids cache or delta splice."""
+    rows = [svc.forward_entries([(g.struct_key_fresh(),
+                                  svc._fresh_ids(g)[0])])[0]
+            for g in graphs]
+    return svc.denormalize_rows(np.stack(rows))
 
 
 def _rewrite_children(graphs, per_rule=2):
@@ -96,17 +102,15 @@ def test_incremental_equals_scratch_across_all_rules():
 
 
 def test_incremental_hashing_flag_restores_scratch_walks():
+    """A rewrite child inherits its parent's hashes, and its key still
+    equals the from-scratch walk that ignores them."""
     g = samplers.sample_graph(np.random.default_rng(3), "bert")
     k = g.struct_key()
-    prev = IRG.set_incremental_hashing(False)
-    try:
-        f = RW.REGISTRY["dtype_narrow"]
-        child = f.apply(g, f.applicable(g)[0])
-        assert child._inherited is None  # no inheritance while disabled
-        assert child.struct_key() == child.struct_key_fresh()
-        assert g.struct_key() == k       # keys agree across modes
-    finally:
-        IRG.set_incremental_hashing(prev)
+    f = RW.REGISTRY["dtype_narrow"]
+    child = f.apply(g, f.applicable(g)[0])
+    assert child._inherited is not None
+    assert child.struct_key() == child.struct_key_fresh()
+    assert g.struct_key() == k == g.struct_key_fresh()
 
 
 def test_rewrite_children_inherit_most_hashes():
@@ -126,19 +130,22 @@ def test_rewrite_children_inherit_most_hashes():
 
 
 # ------------------------------------------------ delta/ids-cache encoding
-def test_fast_and_legacy_predictions_identical(fast_and_legacy):
-    fast, legacy, graphs = fast_and_legacy
+def test_fast_and_legacy_predictions_identical(fast_service):
+    """predict_all (key-first LRU, ids cache, delta splice) equals the
+    from-scratch reference on parents, rewrite children and both."""
+    fast, graphs = fast_service
+    ref, _ = _mk_service()
     children = _rewrite_children(graphs[:10])
     assert children, "rewrites produced no candidates"
     for batch in (graphs, children, graphs + children):
         o1 = fast.predict_all(batch)
-        o2 = legacy.predict_all(batch)
+        o2 = _scratch_predictions(ref, batch)
         for t in fast.heads:
             np.testing.assert_array_equal(o1[t], o2[t])
 
 
-def test_delta_splice_equals_fresh_encode(fast_and_legacy):
-    fast, _, graphs = fast_and_legacy
+def test_delta_splice_equals_fresh_encode(fast_service):
+    fast, graphs = fast_service
     fast.predict_all(graphs)             # parents' ids now cached
     children = _rewrite_children(graphs)
     spliced = 0
@@ -152,8 +159,8 @@ def test_delta_splice_equals_fresh_encode(fast_and_legacy):
     assert spliced > 10                  # the delta path really fired
 
 
-def test_cache_hit_skips_tokenization(fast_and_legacy):
-    fast, _, graphs = fast_and_legacy
+def test_cache_hit_skips_tokenization(fast_service):
+    fast, graphs = fast_service
     g = graphs[0]
     fast.predict_all([g])
     before = fast.phase_stats()["full_encodes"]
@@ -162,8 +169,8 @@ def test_cache_hit_skips_tokenization(fast_and_legacy):
     assert fast.phase_stats()["full_encodes"] == before
 
 
-def test_server_submit_key_first_parity(fast_and_legacy):
-    fast, _, graphs = fast_and_legacy
+def test_server_submit_key_first_parity(fast_service):
+    fast, graphs = fast_service
     direct = fast.predict_all(graphs)
     with CostModelServer(fast, max_batch=16, flush_us=500) as server:
         before = fast.phase_stats()["full_encodes"]
@@ -174,7 +181,8 @@ def test_server_submit_key_first_parity(fast_and_legacy):
 
 
 # ------------------------------------------------------ truncation counter
-def test_truncation_counter_surfaced():
+@pytest.mark.parametrize("path", ["predict_all", "server_submit"])
+def test_truncation_counter_surfaced(path):
     svc, _ = _mk_service(max_seq=32, buckets=(32,))
     rng = np.random.default_rng(1)
     big = None
@@ -182,30 +190,29 @@ def test_truncation_counter_surfaced():
         g = samplers.sample_graph(rng, "bert")
         if len(TOK.graph_tokens(g, "ops")) > 32:
             big = g
+
+    def predict():
+        if path == "predict_all":
+            svc.predict_all([big])
+            return
+        with CostModelServer(svc, max_batch=4, flush_us=200) as server:
+            server.submit(big).result(timeout=120)
+
     assert svc.truncations == 0
-    svc.predict_all([big])
+    predict()
     assert svc.truncations == 1
     assert svc.cache_stats()["truncations"] == 1
-    svc.predict_all([big])               # LRU hit: no new truncation
+    predict()                            # LRU hit: no new truncation
     assert svc.cache_stats()["truncations"] == 1
-
-
-def test_truncation_counter_legacy_path():
-    svc, _ = _mk_service(max_seq=32, buckets=(32,), fast_encode=False)
-    rng = np.random.default_rng(1)
-    g = samplers.sample_graph(rng, "bert")
-    toks = TOK.graph_tokens(g, "ops")
-    svc.predict_all([g])
-    assert svc.truncations == (1 if len(toks) > 32 else 0)
 
 
 # ------------------------------------------------------------ bf16 serving
-def test_bf16_drift_within_gates(fast_and_legacy):
+def test_bf16_drift_within_gates(fast_service):
     """bf16-quantized serving: params cast once, rows widened to f32
     before the (float32-exact) denormalize; prediction drift vs f32 is
     bounded — Spearman >= 0.99 and small relative error per target."""
     from repro.opt.evaluate import spearman
-    fast, _, graphs = fast_and_legacy
+    fast, graphs = fast_service
     bf16, _ = _mk_service(dtype="bf16")
     p32 = fast.predict_all(graphs)
     pbf = bf16.predict_all(graphs)

@@ -178,7 +178,7 @@ def test_lru_eviction_bounds_cache(unified_service, small_dataset):
         mode="ops", max_seq=96, cache_size=8)
     rng = np.random.default_rng(8)
     gs = [samplers.sample_graph(rng) for _ in range(30)]
-    n_unique = len({svc._encode(g).tobytes() for g in gs})
+    n_unique = len({svc._fresh_ids(g)[0].tobytes() for g in gs})
     svc.predict_all(gs)
     assert len(svc._cache) == min(8, n_unique)
     svc.predict_all(gs[-4:])             # refresh recency for these four
